@@ -8,8 +8,8 @@ Headline (printed LAST, the line the driver records):
   check, median of 5 runs so one noisy pair can't flip the artifact
   (round-2 verdict: the single-shot bench recorded a below-baseline
   outlier); per-rep times + spread ride in the JSON, and a >20% median
-  drop vs the BEST of the last 3 rounds (perf ledger + BENCH_r*.json)
-  fails loudly (REGRESSION banner + regression fields). Every full-size
+  drop vs the BEST of the last 3 rounds of the perf ledger fails
+  loudly (REGRESSION banner + regression fields). Every full-size
   round appends a per-kernel entry to bench_ledger.jsonl; an EWMA
   slow-bleed detector (jepsen_tpu.ledger) flags gradual drifts the
   per-round gate can't see, attributed per kernel (wgl/elle/encode).
@@ -32,7 +32,9 @@ individual run times (the box shows up to ~30% run-to-run noise; a
 single-run figure can hide a real regression or fake one).
 
 BENCH_OPS scales config 2 (e.g. BENCH_OPS=100000 for a CPU smoke run);
-BENCH_SKIP_EXTRAS=1 runs the headline config only.
+BENCH_SKIP_EXTRAS=1 runs the headline config only. A config that
+raises is logged, the remaining lines still print, and the script then
+exits 1.
 """
 
 import json
@@ -428,31 +430,6 @@ two-consecutive-15%-drops hole: the second drop is still measured
 against the pre-bleed value."""
 
 
-def _bench_rounds():
-    """[(round, headline-dict, source)] from the driver's BENCH_r<NN>
-    artifacts, round order."""
-    import glob
-    import re
-
-    here = os.path.dirname(os.path.abspath(__file__))
-    paths = sorted(
-        glob.glob(os.path.join(here, "BENCH_r*.json")),
-        key=lambda p: int(re.search(r"r(\d+)", os.path.basename(p))
-                          .group(1)))
-    out = []
-    for p in paths:
-        try:
-            with open(p) as f:
-                parsed = json.load(f).get("parsed")
-            if isinstance(parsed, dict) and parsed.get("value"):
-                rnd = int(re.search(r"r(\d+)", os.path.basename(p))
-                          .group(1))
-                out.append((rnd, parsed, os.path.basename(p)))
-        except (OSError, ValueError):
-            continue
-    return out
-
-
 def _ledger_path():
     from jepsen_tpu import ledger
 
@@ -461,16 +438,11 @@ def _ledger_path():
 
 
 def _previous_headlines(metric):
-    """The last GATE_WINDOW rounds' headline values for `metric`,
-    merged from the perf ledger and the BENCH_r artifacts (the ledger
-    wins when both carry a round — it's written by this script, the
-    artifacts by the driver). Returns [(round, value, source)]."""
+    """The last GATE_WINDOW rounds' headline values for `metric` from
+    this script's perf ledger. Returns [(round, value, source)]."""
     from jepsen_tpu import ledger
 
     by_round = {}
-    for rnd, parsed, src in _bench_rounds():
-        if parsed.get("metric") == metric:
-            by_round[rnd] = (parsed["value"], src)
     for e in ledger.read_entries(_ledger_path()):
         hl = e.get("headline") or {}
         if (hl.get("metric") == metric
@@ -483,7 +455,7 @@ def _previous_headlines(metric):
 
 def _check_regression(line):
     """Compares the new headline median against the BEST of the last
-    GATE_WINDOW rounds (ledger + BENCH artifacts); a >20% drop fails
+    GATE_WINDOW rounds of the perf ledger; a >20% drop fails
     loudly (REGRESSION banner on stderr + regression fields in the
     JSON, so the report can't read a real drop as routine noise).
     Skipped when history sizes differ (BENCH_OPS smoke runs aren't
@@ -1273,9 +1245,8 @@ def _ledger_entry(lines, headline):
     except Exception as e:  # noqa: BLE001 — search stats are extras
         _log(f"search stats unavailable: {e!r}")
     entries = ledger.read_entries(_ledger_path())
-    floor = max((r for r, _p, _s in _bench_rounds()), default=0)
     out = {
-        "round": ledger.next_round(entries, floor=floor),
+        "round": ledger.next_round(entries),
         "kind": "bench",
         "headline": {k: headline.get(k) for k in
                      ("metric", "value", "unit", "runs_s", "spread")},
@@ -1342,95 +1313,15 @@ def _ledger_update(lines, headline):
     return headline
 
 
-def _multichip_lines():
-    """Scaling-attribution line from the newest MULTICHIP_r*.json: the
-    dry run prints `parallel_efficiency {...}` into its tail
-    (__graft_entry__.dryrun_multichip); bench re-checks it so a flat
-    mesh sweep fails loudly in every report, not just the sweep's."""
-    import glob
-    import re
-
-    from jepsen_tpu.tpu import profiler
-
-    here = os.path.dirname(os.path.abspath(__file__))
-    paths = sorted(
-        glob.glob(os.path.join(here, "MULTICHIP_r*.json")),
-        key=lambda p: int(re.search(r"r(\d+)", os.path.basename(p))
-                          .group(1)))
-    eff = None
-    src = None
-    bench_line = None
-    for p in reversed(paths):
-        try:
-            with open(p) as f:
-                doc = json.load(f)
-            tail = str(doc.get("tail", ""))
-            raw = doc.get("parallel_efficiency")
-            if raw is None:
-                m = re.search(r"parallel_efficiency (\{[^}\n]*\})",
-                              tail)
-                raw = json.loads(m.group(1)) if m else None
-            if isinstance(raw, dict) and raw:
-                eff = {int(k): float(v) for k, v in raw.items()}
-                src = os.path.basename(p)
-                # the dry run's sharded-ensemble headline rides the
-                # same tail (BENCH {...}); lift it into the report
-                m = re.search(r"^BENCH (\{.*\})$", tail, re.M)
-                if m:
-                    try:
-                        bench_line = json.loads(m.group(1))
-                        bench_line["source"] = src
-                    except ValueError:
-                        bench_line = None
-                break
-        except (OSError, ValueError):
-            continue
-    if not eff:
-        return []
-    bad = profiler.check_efficiency(eff, log=lambda m: _log(
-        f"!!! {src}: {m}"))
-    n_max = max(eff)
-    _log(f"multichip efficiency ({src}): " + " ".join(
-        f"mesh{n}={e}" for n, e in sorted(eff.items())))
-    lines = [{
-        "metric": f"multichip parallel efficiency at {n_max} devices "
-                  f"(mesh1_time / (mesh{n_max}_time x {n_max}), "
-                  f"from {src})",
-        "value": eff[n_max],
-        "unit": "fraction",
-        "vs_baseline": round(eff[n_max] / 1.0, 4),
-        "flat_mesh": bool(bad),
-    }]
-    if bench_line:
-        lines.append(bench_line)
-    return lines
-
-
-def _enable_compile_cache():
-    """Persistent XLA compilation cache (jepsen_tpu.tpu.spmd): repeat
-    bench runs skip the ~35s one-time kernel compiles. The shared knob
-    is JEPSEN_TPU_COMPILE_CACHE (default under store/); the legacy
-    JAX_COMPILATION_CACHE_DIR still wins for existing bench rigs."""
-    legacy = os.environ.get("JAX_COMPILATION_CACHE_DIR")
-    if legacy:
-        os.environ.setdefault("JEPSEN_TPU_COMPILE_CACHE", legacy)
-    try:
-        from jepsen_tpu.tpu import spmd
-
-        d = spmd.enable_compile_cache()
-        _log(f"compilation cache: {d or 'disabled'}")
-    except Exception as e:  # noqa: BLE001 — cache is best-effort
-        _log(f"compilation cache unavailable: {e!r}")
-
-
 def main():
-    from jepsen_tpu.tpu import dist
+    from jepsen_tpu.tpu import dist, spmd
 
     dist.ensure_initialized()  # before the first JAX computation
-    _enable_compile_cache()
+    _log(f"compilation cache: {spmd.enable_compile_cache()}")
     n_events = int(os.environ.get("BENCH_OPS", "1000000"))
     small = n_events < 1_000_000
     lines = []
+    failed = []
     if not os.environ.get("BENCH_SKIP_EXTRAS"):
         for fn, args in ((bench_warm_start, ()),
                          (bench_monitor_overhead, ()),
@@ -1460,25 +1351,32 @@ def main():
                          (bench_anomaly, (n_events,))):
             try:
                 lines.append(fn(*args))
-            except Exception as e:  # extras must never sink the headline
+            except Exception as e:  # noqa: BLE001 — run the rest, then fail
+                failed.append(fn.__name__)
                 _log(f"{fn.__name__} failed: {e!r}")
         try:
             lines.extend(_fleet_latency_line())
-        except Exception as e:  # noqa: BLE001 — observation line only
+        except Exception as e:  # noqa: BLE001 — run the rest, then fail
+            failed.append("_fleet_latency_line")
             _log(f"fleet-latency line failed: {e!r}")
-    headline = bench_headline(n_events)
-    lines.extend(_telemetry_lines())
     try:
-        lines.extend(_multichip_lines())
-    except Exception as e:  # noqa: BLE001 — attribution lines are extras
-        _log(f"multichip lines failed: {e!r}")
-    if not small and not os.environ.get("BENCH_NO_LEDGER"):
-        # cross-run perf ledger + slow-bleed detection (full-size
-        # rounds only: smoke-run numbers would poison the series)
-        headline = _ledger_update(lines, headline)
-    lines.append(headline)  # the driver records the LAST line
+        headline = bench_headline(n_events)
+    except Exception as e:  # noqa: BLE001 — print the rest, then fail
+        failed.append("bench_headline")
+        _log(f"bench_headline failed: {e!r}")
+        headline = None
+    lines.extend(_telemetry_lines())
+    if headline is not None:
+        if not small and not os.environ.get("BENCH_NO_LEDGER"):
+            # cross-run perf ledger + slow-bleed detection (full-size
+            # rounds only: smoke-run numbers would poison the series)
+            headline = _ledger_update(lines, headline)
+        lines.append(headline)  # the driver records the LAST line
     for ln in lines:
         print(json.dumps(ln))
+    if failed:
+        _log(f"FAILED configs: {', '.join(failed)}")
+        sys.exit(1)
 
 
 if __name__ == "__main__":

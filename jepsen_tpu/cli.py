@@ -69,11 +69,6 @@ def add_test_opts(p: argparse.ArgumentParser) -> argparse.ArgumentParser:
                    help="How many times to repeat the test.")
     p.add_argument("--time-limit", type=int, default=60,
                    help="Test duration excluding setup/teardown, secs.")
-    p.add_argument("--compile-cache", default=None, metavar="DIR",
-                   help="Persistent XLA compilation cache directory "
-                        "(or 0 to disable). Defaults to "
-                        "store/.xla-cache; also settable via "
-                        "JEPSEN_TPU_COMPILE_CACHE (doc/spmd.md).")
     return p
 
 
@@ -103,14 +98,6 @@ def test_opt_fn(options: argparse.Namespace) -> dict:
         "dummy": o.pop("no_ssh", False),
     }
     o["leave_db_running?"] = o.pop("leave_db_running", False)
-    cache = o.pop("compile_cache", None)
-    if cache is not None:
-        # the kernel jit factories read the env knob lazily
-        # (jepsen_tpu.tpu.spmd.enable_compile_cache), so setting it
-        # here covers every checker launch in this process
-        import os
-
-        os.environ["JEPSEN_TPU_COMPILE_CACHE"] = cache
     o.pop("node", None)
     o.pop("nodes_file", None)
     return o

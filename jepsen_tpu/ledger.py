@@ -6,7 +6,7 @@ sail through — exactly how the 1M-event headline bled 77.5k -> 65.2k
 ops/s without a single gate trip. This module closes that hole:
 
   bench_ledger.jsonl   one JSON line per bench round, appended by
-                       bench.py next to the BENCH_r*.json artifacts:
+                       bench.py at the repo root:
                        round id, timestamp, the headline line, and a
                        per-kernel breakdown ({name: {value, unit,
                        higher_is_better}}) so a regression is
@@ -110,12 +110,9 @@ def atomic_append_line(path, line: str) -> None:
         os.close(fd)
 
 
-def next_round(entries: list[dict], floor: int = 0) -> int:
-    """The next round id: one past the ledger's max (and past `floor`,
-    the newest BENCH_r<NN> artifact's round, so ledger rounds stay
-    aligned with the driver's files even if the ledger starts late)."""
-    last = max((int(e.get("round", 0)) for e in entries), default=0)
-    return max(last, floor) + 1
+def next_round(entries: list[dict]) -> int:
+    """The next round id: one past the ledger's max."""
+    return max((int(e.get("round", 0)) for e in entries), default=0) + 1
 
 
 def validate_entries(entries) -> int:

@@ -56,7 +56,6 @@ def _jitted_sharded(mesh, W: int, F: int, max_iters: int, reach: bool,
     """One jitted shard_map program per (mesh, static config); jax.jit
     then caches compiled executables per array shape bucket."""
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import NamedSharding
 
     spmd.enable_compile_cache()
@@ -80,8 +79,8 @@ def _jitted_sharded(mesh, W: int, F: int, max_iters: int, reach: bool,
     from jax.sharding import PartitionSpec as P
 
     out_specs = (P(spmd.AXIS),) * n_res + (P(),) * 4
-    mapped = shard_map(local, mesh=mesh, in_specs=data_specs,
-                       out_specs=out_specs, check_rep=False)
+    mapped = jax.shard_map(local, mesh=mesh, in_specs=data_specs,
+                           out_specs=out_specs, check_vma=False)
 
     def run(inv_t, ret_t, trans, mseg, sufmin, row_seg, st0, inv_perm):
         outs = mapped(inv_t, ret_t, trans, mseg, sufmin, row_seg, st0)

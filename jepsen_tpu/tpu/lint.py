@@ -441,10 +441,13 @@ def rule_sharding(trace: KernelTrace) -> list[Finding]:
 
 def rule_recompile(trace: KernelTrace) -> list[Finding]:
     out: list[Finding] = []
-    consts = [c for c in (getattr(trace.jaxpr, "consts", ()) or ())
-              if getattr(c, "nbytes", None) is not None]
-    big = [c for c in consts if c.nbytes >= LARGE_CONST_BYTES]
-    small = [c for c in consts if c.nbytes < LARGE_CONST_BYTES]
+    # jax keeps closure captures on the ClosedJaxpr as typed arrays
+    # that carry shape and dtype but no nbytes
+    sizes = [_aval_bytes(c)
+             for c in (getattr(trace.jaxpr, "consts", ()) or ())
+             if hasattr(c, "shape") and hasattr(c, "dtype")]
+    big = [b for b in sizes if b >= LARGE_CONST_BYTES]
+    small = [b for b in sizes if b < LARGE_CONST_BYTES]
     if small:
         # every captured value bakes into the traced program: a
         # varying capture (config, rng state, a python-built table)
@@ -459,7 +462,7 @@ def rule_recompile(trace: KernelTrace) -> list[Finding]:
             hint="pass varying values as arguments; keep closure "
                  "captures to true constants"))
     if big:
-        total = sum(int(c.nbytes) for c in big)
+        total = sum(big)
         out.append(Finding(
             rule="R5", kernel=trace.name, site="large-consts",
             message=f"{len(big)} closure-captured array const(s) "

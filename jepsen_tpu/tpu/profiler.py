@@ -29,8 +29,8 @@ always on and adds two dict updates per launch; cost analysis costs one
 lowering per NEW bucket only (JEPSEN_TPU_PROFILE_COST=0 disables it).
 
 Cross-run trending lives in jepsen_tpu.ledger (the bench perf ledger);
-parallel_efficiency() below is the shared scaling metric both the
-multichip dry run and bench report.
+parallel_efficiency() below is the scaling metric that
+`chip_smoke.py --chips 4` reports for the sharded paths.
 """
 
 from __future__ import annotations
@@ -418,8 +418,7 @@ def shape_buckets() -> dict[str, set]:
 def parallel_efficiency(times: dict[int, float]) -> dict[int, float]:
     """Per-mesh-size parallel efficiency from a {n_devices: seconds}
     sweep: eff(N) = T(1) / (T(N) * N). 1.0 = perfect linear scaling;
-    a flat sweep shows ~1/N — the MULTICHIP failure signature this
-    metric machine-checks (ROADMAP item 1)."""
+    a flat sweep shows ~1/N."""
     t1 = times.get(1)
     if not t1:
         return {}
@@ -428,7 +427,7 @@ def parallel_efficiency(times: dict[int, float]) -> dict[int, float]:
 
 
 # Mesh sizes at least this big with efficiency below this floor get a
-# loud warning (bench + the multichip dry run both check it).
+# loud warning (chip_smoke.py --chips 4 checks it).
 EFFICIENCY_WARN_N = 4
 EFFICIENCY_WARN_FLOOR = 0.5
 

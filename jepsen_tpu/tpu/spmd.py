@@ -13,12 +13,10 @@ One place answers three questions every sharded launch site asks:
     lint registry reads the same table, so graftlint R4 prices the
     layout the launch sites actually use — not a parallel description
     that can drift.
-  - *Is the XLA compilation cache on?* `enable_compile_cache()` wires
-    jax's persistent compilation cache behind a CLI/env knob
-    (JEPSEN_TPU_COMPILE_CACHE; default under store/), called lazily by
-    every jit factory — a warm cache makes first-check compile ~0 and
-    un-gates the profiler's memory_analysis path (which needs a second
-    compile per bucket to be cheap).
+  - *Where is the XLA compilation cache?* `enable_compile_cache()`
+    points jax's persistent cache at JAX_COMPILATION_CACHE_DIR, or at
+    `<checkout>/.jax_cache` when that is unset; every jit factory
+    calls it lazily.
 
 The mesh itself is 1-D over the batch axis ("b"): every kernel family
 here is embarrassingly parallel in exactly one axis (histories in the
@@ -30,11 +28,9 @@ serves all of them and `mesh_for(n)` memoizes one Mesh per size.
 from __future__ import annotations
 
 import functools
-import logging
 import os
 import re
-
-logger = logging.getLogger(__name__)
+from pathlib import Path
 
 # The mesh's single batch axis name, shared by every sharded kernel.
 AXIS = "b"
@@ -53,17 +49,13 @@ def spmd_enabled() -> bool:
 def spmd_devices() -> int:
     """How many devices a sharded launch may span right now: the
     process's device count, capped by JEPSEN_TPU_SPMD_DEVICES (tests
-    parametrize mesh sizes through it), 0 when sharding is disabled
-    or jax is unavailable. Values <= 1 mean 'take the single-device
-    path'."""
+    parametrize mesh sizes through it), 0 when sharding is disabled.
+    Values <= 1 mean 'take the single-device path'."""
     if not spmd_enabled():
         return 0
-    try:
-        import jax
+    import jax
 
-        n = len(jax.devices())
-    except Exception:  # noqa: BLE001 — no backend, no mesh
-        return 0
+    n = len(jax.devices())
     cap = os.environ.get("JEPSEN_TPU_SPMD_DEVICES")
     if cap:
         try:
@@ -152,48 +144,26 @@ def describe_partition(rules, names) -> dict:
 # Persistent XLA compilation cache
 # ---------------------------------------------------------------------------
 
-_cache_done = False
+# Where the cache lives when JAX_COMPILATION_CACHE_DIR is unset: one
+# fixed path inside the checkout, resolved from this file and not from
+# the working directory — the path is part of what makes a cache hit.
+DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
-def compile_cache_dir() -> str | None:
-    """The configured cache directory: JEPSEN_TPU_COMPILE_CACHE (a
-    path, or '0'/'' to disable), defaulting under the store directory
-    (store/.xla-cache) so a repo checkout warms up across runs."""
-    env = os.environ.get("JEPSEN_TPU_COMPILE_CACHE")
-    if env is not None:
-        if env in ("0", ""):
-            return None
-        return env
-    from .. import store
+def enable_compile_cache() -> str:
+    """Points jax's persistent compilation cache at its one directory
+    and returns it: JAX_COMPILATION_CACHE_DIR when set (jax reads it at
+    import, and this leaves it alone), else DEFAULT_CACHE_DIR. Called
+    lazily by every kernel jit factory, before the first compile and
+    never at import. A warm cache makes first-check compile ~0 and lets
+    the profiler's memory_analysis compile come from disk."""
+    import jax
 
-    return str(store.BASE / ".xla-cache")
-
-
-def enable_compile_cache() -> str | None:
-    """Idempotently points jax's persistent compilation cache at
-    compile_cache_dir(). Called by every kernel jit factory (lazily —
-    before the first compile, never at import). A dir already set
-    through jax.config (bench, tests/conftest.py) wins; returns the
-    active dir or None. First-check compile on a warm cache is ~0
-    (bench_warm_start measures it) and a configured cache un-gates
-    profiler memory_analysis."""
-    global _cache_done
-    try:
-        import jax
-
-        if jax.config.jax_compilation_cache_dir:
-            return jax.config.jax_compilation_cache_dir
-        if _cache_done:
-            return None
-        _cache_done = True
-        d = compile_cache_dir()
-        if d is None:
-            return None
+    d = jax.config.jax_compilation_cache_dir
+    if not d:
+        d = str(DEFAULT_CACHE_DIR)
         os.makedirs(d, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", d)
         jax.config.update(
             "jax_persistent_cache_min_compile_time_secs", 0.5)
-        return d
-    except Exception as e:  # noqa: BLE001 — cache is best-effort
-        logger.debug("compilation cache unavailable: %r", e)
-        return None
+    return d

@@ -366,10 +366,7 @@ def profile_trace(trace_dir=None):
     if not trace_dir:
         yield
         return
-    try:
-        import jax
-    except ImportError:
-        yield
-        return
+    import jax
+
     with jax.profiler.trace(str(trace_dir)):
         yield

@@ -72,7 +72,7 @@ class TestR2Widening:
         import jax
         import jax.numpy as jnp
 
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             def k(x):
                 return jnp.sum(x.astype(jnp.int64))
 

@@ -413,7 +413,6 @@ class TestLedger:
         entries = ledger.read_entries(path)
         assert ledger.validate_entries(entries) == 3
         assert ledger.next_round(entries) == 4
-        assert ledger.next_round(entries, floor=9) == 10
         # torn trailing line is dropped, not raised
         with open(path, "a") as f:
             f.write('{"round": 4, "ts"')
@@ -446,7 +445,6 @@ class TestRegressionGate:
                              "unit": "ops/s"},
                 "kernels": {}})
         monkeypatch.setattr(bench, "_ledger_path", lambda: str(path))
-        monkeypatch.setattr(bench, "_bench_rounds", lambda: [])
         return bench
 
     def test_two_15pct_drops_trip_the_gate(self, monkeypatch,
@@ -500,6 +498,25 @@ class TestRegressionGate:
         ledger.validate_entries([entry | {"ts": 1.0}])
 
 
+def test_bench_exits_nonzero_after_printing_when_a_config_raises(
+        monkeypatch, capsys):
+    import bench
+
+    def boom(_n):
+        raise RuntimeError("config failed")
+
+    monkeypatch.setenv("BENCH_SKIP_EXTRAS", "1")
+    monkeypatch.setattr(bench, "bench_headline", boom)
+    monkeypatch.setattr(bench, "_telemetry_lines",
+                        lambda: [{"metric": "telemetry", "value": 1}])
+    with pytest.raises(SystemExit) as exc:
+        bench.main()
+    assert exc.value.code == 1
+    out = capsys.readouterr()
+    assert '"metric": "telemetry"' in out.out
+    assert "bench_headline failed" in out.err
+
+
 class TestScalingAttribution:
     def test_parallel_efficiency(self):
         eff = profiler.parallel_efficiency(
@@ -507,7 +524,7 @@ class TestScalingAttribution:
         assert eff == {1: 1.0, 2: 1.0, 4: 1.0, 8: 1.0}
         flat = profiler.parallel_efficiency(
             {1: 3.77, 2: 3.43, 4: 3.29, 8: 3.43})
-        assert flat[8] < 0.2  # the MULTICHIP_r05 failure signature
+        assert flat[8] < 0.2  # a flat sweep
         assert profiler.parallel_efficiency({2: 1.0}) == {}
 
     def test_check_efficiency_warns_below_floor(self):

@@ -20,6 +20,8 @@ Pins the whole acceptance surface of the SPMD rebuild:
 
 from __future__ import annotations
 
+import os
+
 import numpy as np
 import pytest
 
@@ -114,14 +116,33 @@ class TestPlumbing:
                                      "st0"}
         assert d["replicated"] == ["inv_perm"]
 
-    def test_compile_cache_knob(self, monkeypatch):
-        monkeypatch.setenv("JEPSEN_TPU_COMPILE_CACHE", "0")
-        assert spmd.compile_cache_dir() is None
-        monkeypatch.setenv("JEPSEN_TPU_COMPILE_CACHE", "/tmp/x")
-        assert spmd.compile_cache_dir() == "/tmp/x"
-        monkeypatch.delenv("JEPSEN_TPU_COMPILE_CACHE", raising=False)
-        d = spmd.compile_cache_dir()
-        assert d and d.endswith(".xla-cache")
+    def test_compile_cache_placement(self, tmp_path):
+        import subprocess
+        import sys
+        from pathlib import Path
+
+        repo = Path(__file__).resolve().parents[1]
+        assert spmd.DEFAULT_CACHE_DIR == repo / ".jax_cache"
+        probe = ("import jax; from jepsen_tpu.tpu import spmd; "
+                 "print(spmd.enable_compile_cache()); "
+                 "print(jax.config.jax_compilation_cache_dir)")
+
+        def run(env_dir):
+            env = {k: v for k, v in os.environ.items()
+                   if k != "JAX_COMPILATION_CACHE_DIR"}
+            if env_dir is not None:
+                env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+            # from another working directory: the default must not
+            # move with cd
+            out = subprocess.run(
+                [sys.executable, "-c", probe], cwd=tmp_path, env=env
+                | {"PYTHONPATH": str(repo)}, capture_output=True,
+                text=True, timeout=120, check=True)
+            return out.stdout.split()
+
+        assert run(None) == [str(repo / ".jax_cache")] * 2
+        outside = str(tmp_path / "cache")
+        assert run(outside) == [outside] * 2
 
     def test_balanced_groups(self):
         groups = balanced_groups([10, 1, 9, 2, 8, 3], 2)
