@@ -1138,6 +1138,16 @@ def _timed(f) -> float:
     return time.time() - t0
 
 
+# the wgl launch family: single-device, reach and mesh-sharded kernels
+_WGL_KERNELS = ("wgl", "wgl-reach", "wgl-sharded")
+
+
+def _wgl_counter(counters: dict, field: str) -> int:
+    """profiler.<kernel>.<field> summed over the wgl launch family."""
+    return sum(counters.get(f"profiler.{k}.{field}", 0)
+               for k in _WGL_KERNELS)
+
+
 def _telemetry_lines():
     """Kernel-profile lines derived from the run's telemetry: the
     process-global recorder accumulated compile/execute time and batch
@@ -1156,13 +1166,14 @@ def _telemetry_lines():
             with open(mpath) as f:
                 metrics = json.load(f)
         c = metrics.get("counters", {})
-        compile_ns = c.get("wgl.kernel.compile_ns", 0)
-        execute_ns = c.get("wgl.kernel.execute_ns", 0)
+        compile_ns = _wgl_counter(c, "compile_ns")
+        execute_ns = (_wgl_counter(c, "compute_ns")
+                      + _wgl_counter(c, "d2h_ns"))
         if compile_ns or execute_ns:
             _log(f"telemetry: kernel compile {compile_ns / 1e9:.2f}s "
                  f"execute {execute_ns / 1e9:.2f}s over "
-                 f"{c.get('wgl.kernel.launches', 0)} launches "
-                 f"({c.get('wgl.kernel.iterations', 0)} iterations)")
+                 f"{_wgl_counter(c, 'launches')} launches "
+                 f"({_wgl_counter(c, 'iterations')} iterations)")
             lines.append({
                 "metric": "wgl kernel compile share of device time "
                           "(compile_ns / (compile_ns + execute_ns))",
@@ -1235,13 +1246,13 @@ def _ledger_entry(lines, headline):
 
         c = telemetry.get().counters()
         g = telemetry.get().gauges()
-        if c.get("wgl.search.states"):
-            search["states_explored"] = int(c["wgl.search.states"])
-            search["dedup_hits"] = int(c.get("wgl.search.dedup-hits",
-                                             0))
-        if g.get("wgl.search.frontier-peak"):
-            search["frontier_peak"] = int(
-                g["wgl.search.frontier-peak"])
+        if _wgl_counter(c, "states"):
+            search["states_explored"] = _wgl_counter(c, "states")
+            search["dedup_hits"] = _wgl_counter(c, "dedup_hits")
+        peak = max(g.get(f"profiler.{k}.frontier_peak", 0)
+                   for k in _WGL_KERNELS)
+        if peak:
+            search["frontier_peak"] = int(peak)
     except Exception as e:  # noqa: BLE001 — search stats are extras
         _log(f"search stats unavailable: {e!r}")
     entries = ledger.read_entries(_ledger_path())

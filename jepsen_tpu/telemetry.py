@@ -11,8 +11,9 @@ pipeline reports through —
     case, snarf-logs, per-checker timing)
   - interpreter:         per-worker dispatch + generator-stall counters
   - nemesis:             fault-activation spans
-  - tpu/wgl.py:          kernel compile vs execute time, while-loop
-    iteration counts, PackedBatch occupancy, checkpoint save/load
+  - tpu/wgl.py:          the stage spans of a verdict (wgl.*), rows
+    left to the exact host search, PackedBatch occupancy, checkpoint
+    save/load (kernel launches report through tpu/profiler.py)
   - tpu/elle_device.py + tpu/scc.py: graph sizes, SCC sizes,
     host-vs-device path taken
 
@@ -37,12 +38,20 @@ Serialization (written by core.run into the test's store directory):
 The process-global recorder is always on; record calls are a dict
 update under one lock, cheap enough for per-op counters. reset() is
 called at the top of each core.run so artifacts are scoped per run.
+
+While a JAX profiler trace is capturing (util.profile_trace, the CLI's
+--xla-trace, any jax.profiler.start_trace), every span() is also a
+jax.profiler.TraceAnnotation of the same name, so program spans sit in
+the trace's host plane on the device trace's own clock. This module
+never imports JAX: without it, or with no trace capturing, a span pays
+one module lookup and one call.
 """
 
 from __future__ import annotations
 
 import json
 import logging
+import sys
 import threading
 from contextlib import contextmanager
 from pathlib import Path
@@ -54,6 +63,19 @@ logger = logging.getLogger(__name__)
 
 TRACE_FILE = "telemetry.jsonl"
 METRICS_FILE = "metrics.json"
+
+
+def _profiler_annotation(name: str):
+    """A jax.profiler.TraceAnnotation(name), not yet entered, while a
+    JAX profiler trace is capturing; else None. Looks JAX up only if
+    something else already imported it."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    ann = getattr(getattr(jax, "profiler", None), "TraceAnnotation", None)
+    if ann is None or not ann.is_enabled():
+        return None
+    return ann(name)
 
 
 class Telemetry:
@@ -89,7 +111,9 @@ class Telemetry:
     @contextmanager
     def span(self, name: str, **attrs):
         """Context manager recording a named interval. Yields the span
-        record (mutable: add attrs mid-flight via rec['attrs'])."""
+        record (mutable: add attrs mid-flight via rec['attrs']). While
+        a JAX profiler trace is capturing, the interval is also a
+        TraceAnnotation of the same name (module docstring)."""
         if not self.enabled:
             yield None
             return
@@ -114,9 +138,14 @@ class Telemetry:
             # it to stream what the run is doing *right now*
             if self._epoch == epoch0:
                 self._open[sid] = rec
+        ann = _profiler_annotation(name)
+        if ann is not None:
+            ann.__enter__()
         try:
             yield rec
         finally:
+            if ann is not None:
+                ann.__exit__(None, None, None)
             rec["t1"] = util.relative_time_nanos()
             stack.pop()
             with self._lock:

@@ -224,7 +224,6 @@ def sharded_launch(pb: PackedBatch, rows: Sequence[tuple[int, int]],
     bucket = (mesh, lay.inv_t.shape, lay.trans.shape[2],
               len(lay.row_seg), len(lay.inv_perm), W, F, pb.M + 4,
               reach, pb.has_crashed)
-    telemetry.count("wgl.kernel.rows", len(lay.row_seg))
     telemetry.count("wgl.spmd.launches")
     telemetry.gauge_max("wgl.spmd.devices", n_dev)
     balance = profiler.work_balance(lay.device_entries)
@@ -266,16 +265,21 @@ def analysis_batch_sharded(model, hists, mesh=None, W: int | None = None,
     from .encode import EncodingError, encode
 
     encs, idx_map, results = [], [], [None] * len(hists)
-    for i, hh in enumerate(hists):
-        if not isinstance(hh, History):
-            hh = History(hh)
-        try:
-            encs.append(encode(model, hh))
-            idx_map.append(i)
-        except EncodingError:
-            out = wgl_mod.search_host_model(model, hh, witness=True)
-            out["analyzer"] = "model"
-            results[i] = out
+    unencodable = []
+    # one encode span for the whole batch, not one per history
+    with telemetry.span("wgl.encode"):
+        for i, hh in enumerate(hists):
+            if not isinstance(hh, History):
+                hh = History(hh)
+            try:
+                encs.append(encode(model, hh))
+                idx_map.append(i)
+            except EncodingError:
+                unencodable.append((i, hh))
+    for i, hh in unencodable:
+        out = wgl_mod.search_host_model(model, hh, witness=True)
+        out["analyzer"] = "model"
+        results[i] = out
     if encs:
         from .wgl import RangeError
         try:

@@ -155,7 +155,15 @@ def search_host(enc: Encoded, witness: bool = False) -> dict:
 def search_host_reach(enc: Encoded) -> int:
     """Exhaustive host search returning the bitmask of model states the
     history can end in (0 = not linearizable). Host analog of the
-    kernel's reach mode, for per-segment fallback."""
+    kernel's reach mode, for per-segment fallback. Every row the device
+    leaves to the host comes through here: one `wgl.host-reach` span
+    and one `wgl.host-reach.rows` count per call."""
+    telemetry.count("wgl.host-reach.rows")
+    with telemetry.span("wgl.host-reach"):
+        return _search_host_reach(enc)
+
+
+def _search_host_reach(enc: Encoded) -> int:
     m = enc.m
     if m == 0:
         return 1 << enc.init_state
@@ -405,6 +413,10 @@ class PackedBatch:
                  "st0", "M", "S", "B", "has_crashed")
 
     def __init__(self, encs: Sequence[Encoded]):
+        with telemetry.span("wgl.pack"):
+            self._pack(encs)
+
+    def _pack(self, encs: Sequence[Encoded]) -> None:
         _pack_t0 = _time.monotonic_ns()
         B = len(encs)
         self.has_crashed = any(bool(e.crashed.any()) for e in encs)
@@ -536,7 +548,7 @@ def _kernel(inv_t, ret_t, trans, mseg, sufmin, row_seg, st0,
     [max_iters] level-series — live frontier configs entering each BFS
     level, unique successor states produced by it, and dedup hits
     (generated minus unique) — summed over the batch. _drain folds
-    them into profiler records and wgl.search.* telemetry; cost is
+    them into profiler records (profiler.<kernel>.* counters); cost is
     three scalar reductions + dynamic_update_slice per level."""
     import jax
     import jax.numpy as jnp
@@ -799,14 +811,10 @@ def _timed_launch(bucket, dispatch, kernel: str = "wgl", lower=None,
     rec["dispatch_ns"] = _time.monotonic_ns() - t0
     if fresh:
         jax.block_until_ready(out)
-        compile_ns = _time.monotonic_ns() - t0
-        tel.count("wgl.kernel.compiles")
-        tel.count("wgl.kernel.compile_ns", compile_ns)
-        rec["compile_ns"] = compile_ns
+        rec["compile_ns"] = _time.monotonic_ns() - t0
     # cost analysis: computed once right after the bucket's compile
     # (the executable cache is warm), replayed from cache for hits
     rec.update(prof.bucket_cost(bucket, lower, fresh))
-    tel.count("wgl.kernel.launches")
     return prof.attach(out, rec)
 
 
@@ -842,7 +850,6 @@ def _launch(pb: PackedBatch, rows: Sequence[tuple[int, int]], W: int,
     h2d_ns = _time.monotonic_ns() - t0
     bucket = (pb.inv_t.shape, pb.trans.shape[2], len(row_seg), W, F,
               pb.M + 4, reach, pb.has_crashed)
-    telemetry.count("wgl.kernel.rows", len(row_seg))
     kw = dict(W=W, F=F, max_iters=pb.M + 4, reach=reach,
               crash_free=not pb.has_crashed)
     meta = {"h2d_ns": h2d_ns, "rows": len(row_seg), "batch": pb.B,
@@ -864,14 +871,13 @@ def _downsample(xs, n: int = 32) -> list[int]:
 
 
 def _drain(out, reach: bool):
-    """Materializes a launch's outputs (blocking on the device),
-    recording the host wait as execute time plus the kernel's
-    while-loop iteration count and search-shape series (frontier
-    occupancy / states explored / dedup hits per BFS level), and
-    closing the launch's profiler record (device-compute wait, D2H
-    readback). Returns result [B] (reach=False) or (out_mask, unknown)
-    arrays (reach=True)."""
-    tel = telemetry.get()
+    """Materializes a launch's outputs (blocking on the device) and
+    closes the launch's profiler record with the device-compute wait,
+    the D2H readback, the kernel's while-loop iteration count and its
+    search-shape series (frontier occupancy / states explored / dedup
+    hits per BFS level); the profiler turns them into
+    profiler.<kernel>.* counters. Returns result [B] (reach=False) or
+    (out_mask, unknown) arrays (reach=True)."""
     prof = profiler.get()
     rec = prof.take(out)
     t0 = _time.monotonic_ns()
@@ -896,15 +902,7 @@ def _drain(out, reach: bool):
     states = int(new.sum())
     dedup = int(dup.sum())
     t1 = _time.monotonic_ns()
-    tel.count("wgl.kernel.execute_ns", t1 - t0)
-    tel.count("wgl.kernel.iterations", n_it)
-    # the search explorer's aggregate counters (per-launch series ride
-    # in the profiler record / kernel:<k> span attrs)
-    tel.count("wgl.search.levels", n_it)
-    tel.count("wgl.search.states", states)
-    tel.count("wgl.search.dedup-hits", dedup)
-    if peak:
-        tel.gauge_max("wgl.search.frontier-peak", peak)
+    # the per-launch series ride in the kernel:<k> span attrs
     if rec is not None:
         rec["compute_ns"] = t_ready - t0
         rec["d2h_ns"] = t1 - t_ready
@@ -1197,6 +1195,71 @@ def _wave_bounds(K: int, early: bool) -> list[tuple[int, int]]:
     return out
 
 
+def _screen_wave(enc: Encoded, segs, cuts, vcuts, lo: int, hi: int,
+                 S: int, W: int, F: int, prefix_screen: int,
+                 resolved: dict) -> list[tuple[int, int]]:
+    """The device prefix screen of one wave (see check_segmented's
+    prefix_screen): resolves the rows it can into `resolved` and
+    returns the (segment, start-state) rows left for the main launch."""
+    rows: list[tuple[int, int]] = []
+    # Screening runs ON DEVICE: all (segment, start-state) prefix
+    # rows go up in one small batched reach launch (the prefixes
+    # bucket to one tiny kernel shape), replacing K x S sequential
+    # host searches. Rare UNKNOWN prefix rows fall back to the
+    # exact host search.
+    screen_rows: list[tuple[int, int]] = []
+    screen_segs: dict[int, tuple] = {}  # k -> (pre_enc, exact)
+    for k in range(lo, hi):
+        klo, khi = cuts[k], cuts[k + 1]
+        j = np.searchsorted(vcuts, klo + prefix_screen)
+        pre_end = int(vcuts[j]) if (j < len(vcuts)
+                                    and vcuts[j] < khi) else khi
+        if (pre_end - klo > 2 * prefix_screen
+                or enc.crashed[klo:pre_end].any()):
+            # No NEARBY interior cut (one such "prefix" would pad
+            # the whole screen batch up to its length), or crashed
+            # entries in the prefix: screening can't shrink the
+            # work cheaply — leave every state to the main launch
+            # (minus checkpoint-restored entries).
+            rows.extend((k, s) for s in range(S)
+                        if resolved.get((k, s)) is None)
+            continue
+        exact = pre_end == khi
+        pre = segs[k] if exact else enc.segment(klo, pre_end)
+        screen_segs[k] = (pre, exact)
+        screen_rows.extend((k, s) for s in range(S)
+                           if resolved.get((k, s)) is None)
+    if screen_rows:
+        ks = sorted(screen_segs)
+        kidx = {k: i for i, k in enumerate(ks)}
+        launch_rows = [(kidx[k], s) for k, s in screen_rows]
+        try:
+            pre_pb = PackedBatch([screen_segs[k][0] for k in ks])
+            p_out, p_unk = _drain(
+                _launch(pre_pb, launch_rows, W, F, reach=True),
+                reach=True)
+            p_out = p_out[:len(launch_rows)]
+            p_unk = p_unk[:len(launch_rows)]
+        except Exception as e:  # noqa: BLE001 — ladder rung
+            # screen launch failed: every screened row resolves on
+            # host (the exact search — sound, just slower)
+            _ladder_classify(e, "segmented prefix screen")
+            _ladder_note("segment-host-screen")
+            p_out = np.zeros(len(launch_rows), dtype=np.uint32)
+            p_unk = np.ones(len(launch_rows), dtype=bool)
+        for i, (k, s) in enumerate(screen_rows):
+            pre, exact = screen_segs[k]
+            mask = (search_host_reach(pre.with_init(s))
+                    if p_unk[i] else int(p_out[i]))
+            if exact:
+                resolved[(k, s)] = mask
+            elif mask == 0:
+                resolved[(k, s)] = 0
+            else:
+                rows.append((k, s))
+    return rows
+
+
 def _resolve_wave(enc: Encoded, segs, cuts, vcuts, lo: int, hi: int,
                   S: int, W: int, F: int, prefix_screen: int,
                   resolved: dict, pad_to: int | None = None) -> None:
@@ -1207,63 +1270,10 @@ def _resolve_wave(enc: Encoded, segs, cuts, vcuts, lo: int, hi: int,
     leave rows at None for the caller's lazy host floor. pad_to pads
     the wave's packed batch with empty segments so wave launches
     bucket to a fixed set of compile shapes."""
-    rows: list[tuple[int, int]] = []
     if prefix_screen:
-        # Screening runs ON DEVICE: all (segment, start-state) prefix
-        # rows go up in one small batched reach launch (the prefixes
-        # bucket to one tiny kernel shape), replacing K x S sequential
-        # host searches. Rare UNKNOWN prefix rows fall back to the
-        # exact host search.
-        screen_rows: list[tuple[int, int]] = []
-        screen_segs: dict[int, tuple] = {}  # k -> (pre_enc, exact)
-        for k in range(lo, hi):
-            klo, khi = cuts[k], cuts[k + 1]
-            j = np.searchsorted(vcuts, klo + prefix_screen)
-            pre_end = int(vcuts[j]) if (j < len(vcuts)
-                                        and vcuts[j] < khi) else khi
-            if (pre_end - klo > 2 * prefix_screen
-                    or enc.crashed[klo:pre_end].any()):
-                # No NEARBY interior cut (one such "prefix" would pad
-                # the whole screen batch up to its length), or crashed
-                # entries in the prefix: screening can't shrink the
-                # work cheaply — leave every state to the main launch
-                # (minus checkpoint-restored entries).
-                rows.extend((k, s) for s in range(S)
-                            if resolved.get((k, s)) is None)
-                continue
-            exact = pre_end == khi
-            pre = segs[k] if exact else enc.segment(klo, pre_end)
-            screen_segs[k] = (pre, exact)
-            screen_rows.extend((k, s) for s in range(S)
-                               if resolved.get((k, s)) is None)
-        if screen_rows:
-            ks = sorted(screen_segs)
-            kidx = {k: i for i, k in enumerate(ks)}
-            launch_rows = [(kidx[k], s) for k, s in screen_rows]
-            try:
-                pre_pb = PackedBatch([screen_segs[k][0] for k in ks])
-                p_out, p_unk = _drain(
-                    _launch(pre_pb, launch_rows, W, F, reach=True),
-                    reach=True)
-                p_out = p_out[:len(launch_rows)]
-                p_unk = p_unk[:len(launch_rows)]
-            except Exception as e:  # noqa: BLE001 — ladder rung
-                # screen launch failed: every screened row resolves on
-                # host (the exact search — sound, just slower)
-                _ladder_classify(e, "segmented prefix screen")
-                _ladder_note("segment-host-screen")
-                p_out = np.zeros(len(launch_rows), dtype=np.uint32)
-                p_unk = np.ones(len(launch_rows), dtype=bool)
-            for i, (k, s) in enumerate(screen_rows):
-                pre, exact = screen_segs[k]
-                mask = (search_host_reach(pre.with_init(s))
-                        if p_unk[i] else int(p_out[i]))
-                if exact:
-                    resolved[(k, s)] = mask
-                elif mask == 0:
-                    resolved[(k, s)] = 0
-                else:
-                    rows.append((k, s))
+        with telemetry.span("wgl.screen"):
+            rows = _screen_wave(enc, segs, cuts, vcuts, lo, hi, S, W, F,
+                                prefix_screen, resolved)
     else:
         rows = [(k, s) for k in range(lo, hi) for s in range(S)
                 if resolved.get((k, s)) is None]
@@ -1337,7 +1347,13 @@ def check_segmented(enc: Encoded, target_len: int | None = None,
     anomaly localizes; doc/spmd.md documents the semantics). Verdicts,
     masks, witnesses and certificates are identical either way — a
     wave resolves exactly the masks the single launch would have, and
-    composition stops at the same failed segment."""
+    composition stops at the same failed segment.
+
+    Stage spans: `wgl.segment` (cut points and segments), then per
+    wave `wgl.screen` and `wgl.compose` (the composition, with its
+    lazy host floor); `wgl.host-reach.rows` starts at 0 here, so a
+    verdict that sent no row to the host search still reports it."""
+    telemetry.count("wgl.host-reach.rows", 0)
     if enc.n_states > 32:
         # the per-(segment, state) reach masks are uint32 bitmasks; a
         # bigger state space silently fell back to the whole-history
@@ -1358,15 +1374,16 @@ def check_segmented(enc: Encoded, target_len: int | None = None,
         # histories still need >= ~8 segments for the batch dimension
         # (and for checkpointing) to exist at all
         target_len = min(8192, max(256, enc.m // 8))
-    vcuts = valid_cut_points(enc)
-    cuts = segment_cuts(enc, target_len, vcuts=vcuts)
-    K = len(cuts) - 1
-    if K < 2:
-        return None
-    if 2 * max(cuts[k + 1] - cuts[k] for k in range(K)) >= (1 << 21):
-        return None  # a segment alone exceeds the kernel range
+    with telemetry.span("wgl.segment"):
+        vcuts = valid_cut_points(enc)
+        cuts = segment_cuts(enc, target_len, vcuts=vcuts)
+        K = len(cuts) - 1
+        if K < 2:
+            return None
+        if 2 * max(cuts[k + 1] - cuts[k] for k in range(K)) >= (1 << 21):
+            return None  # a segment alone exceeds the kernel range
+        segs = [enc.segment(cuts[k], cuts[k + 1]) for k in range(K)]
     S = enc.n_states
-    segs = [enc.segment(cuts[k], cuts[k + 1]) for k in range(K)]
     # resolved mask per (segment, start-state); None = device said
     # UNKNOWN, resolve lazily on host ONLY if the composition actually
     # reaches that state (unknown rows are the hardest searches).
@@ -1398,23 +1415,26 @@ def check_segmented(enc: Encoded, target_len: int | None = None,
                               if len(waves) > 1 else None))
         if ckpt is not None:
             ckpt.save(resolved)
-        for k in range(lo, hi):
-            nreach = 0
-            for s in range(S):
-                if (reach >> s) & 1:
-                    mask = resolved.get((k, s))
-                    if mask is None:
-                        mask = search_host_reach(segs[k].with_init(s))
-                        resolved[(k, s)] = mask
-                        if ckpt is not None:
-                            ckpt.save_one(k, s, mask)
-                    nreach |= mask
-            if nreach == 0:
-                failed_k = k
-                wstate = next(s for s in range(S) if (reach >> s) & 1)
-                break
-            reach = nreach
-            reaches.append(reach)
+        with telemetry.span("wgl.compose"):
+            for k in range(lo, hi):
+                nreach = 0
+                for s in range(S):
+                    if (reach >> s) & 1:
+                        mask = resolved.get((k, s))
+                        if mask is None:
+                            mask = search_host_reach(
+                                segs[k].with_init(s))
+                            resolved[(k, s)] = mask
+                            if ckpt is not None:
+                                ckpt.save_one(k, s, mask)
+                        nreach |= mask
+                if nreach == 0:
+                    failed_k = k
+                    wstate = next(s for s in range(S)
+                                  if (reach >> s) & 1)
+                    break
+                reach = nreach
+                reaches.append(reach)
         if failed_k is not None:
             if hi < K:
                 # the early-exit payoff: segments past the witness's
@@ -1565,6 +1585,7 @@ def check_extend(enc: Encoded, record: dict | None = None,
     search chain alone) — are identical by construction."""
     from . import ckpt as ckpt_mod
 
+    telemetry.count("wgl.host-reach.rows", 0)  # see check_segmented
     if enc.n_states > 32:
         return None, None
     vcuts = valid_cut_points(enc)
@@ -1781,14 +1802,19 @@ def analysis(model, hist, algorithm: str = "tpu", W: int | None = None,
     don't) additionally attaches a machine-checkable proof of the
     verdict as result['certificate'] (jepsen_tpu.tpu.certify) — for
     valid, a per-segment linearization order re-derived from the reach
-    chain; for invalid, the replayable blocked-frontier witness."""
+    chain; for invalid, the replayable blocked-frontier witness.
+
+    The verdict is one `wgl.analysis` span (its stages nest in it:
+    `wgl.encode`, then check_segmented's); certificate extraction
+    follows it as `certify.attach`."""
     with _ladder_scope() as steps:
         enc_box: list = [None]
-        out = _analysis(model, hist, algorithm, W, F, checkpoint_path,
-                        checkpoint_dir, enc_box)
-        if steps:
-            out["degradation"] = list(steps)
-        _search_stats(out)
+        with telemetry.span("wgl.analysis"):
+            out = _analysis(model, hist, algorithm, W, F,
+                            checkpoint_path, checkpoint_dir, enc_box)
+            if steps:
+                out["degradation"] = list(steps)
+            _search_stats(out)
         if certify:
             from . import certify as certify_mod
 
@@ -1807,40 +1833,17 @@ def analysis_extend(model, hist, store_path=None,
     torn, stale, or wrong-model record costs a full re-check — never a
     wrong verdict. Histories that don't segment (too short, > 32
     states, unencodable) fall through to plain analysis(), so this is
-    always safe to call where analysis() was."""
-    from . import ckpt as ckpt_mod
-
+    always safe to call where analysis() was. Spans as analysis()'s."""
     with _ladder_scope() as steps:
         if not isinstance(hist, History):
             hist = History(hist)
-        enc = None
-        try:
-            enc = encode(model, hist)
-        except EncodingError:
-            pass
-        out = None
-        new_rec = None
-        if enc is not None:
-            record = None
-            if store_path is not None:
-                record = ckpt_mod.load(store_path, "wgl-extend")
-            out, new_rec = check_extend(
-                enc, record=record, stride=stride,
-                **_seg_kwargs(W, F))
+        with telemetry.span("wgl.analysis"):
+            enc, out = _analysis_extend(model, hist, store_path, stride,
+                                        W, F, steps)
         if out is None:
             telemetry.count("ckpt.extend.fallback")
             return analysis(model, hist, algorithm="tpu", W=W, F=F,
                             certify=certify)
-        out["analyzer"] = "tpu-extend"
-        _witness_op_indices(out)
-        if steps:
-            out["degradation"] = list(steps)
-        _search_stats(out)
-        if store_path is not None and new_rec is not None:
-            # best-effort durability: a failed write (ENOSPC/EIO)
-            # leaves the previous record in place — degraded, not
-            # wrong — and the verdict still stands
-            ckpt_mod.try_write(store_path, new_rec)
         if certify:
             from . import certify as certify_mod
 
@@ -1848,12 +1851,45 @@ def analysis_extend(model, hist, store_path=None,
         return out
 
 
+def _analysis_extend(model, hist: History, store_path, stride: int,
+                     W: int | None, F: int | None,
+                     steps: list) -> tuple[Encoded | None, dict | None]:
+    """analysis_extend's verdict: (enc, result), result None where the
+    history does not encode or segment."""
+    from . import ckpt as ckpt_mod
+
+    try:
+        with telemetry.span("wgl.encode"):
+            enc = encode(model, hist)
+    except EncodingError:
+        return None, None
+    record = None
+    if store_path is not None:
+        record = ckpt_mod.load(store_path, "wgl-extend")
+    out, new_rec = check_extend(enc, record=record, stride=stride,
+                                **_seg_kwargs(W, F))
+    if out is None:
+        return enc, None
+    out["analyzer"] = "tpu-extend"
+    _witness_op_indices(out)
+    if steps:
+        out["degradation"] = list(steps)
+    _search_stats(out)
+    if store_path is not None and new_rec is not None:
+        # best-effort durability: a failed write (ENOSPC/EIO)
+        # leaves the previous record in place — degraded, not
+        # wrong — and the verdict still stands
+        ckpt_mod.try_write(store_path, new_rec)
+    return enc, out
+
+
 def _analysis(model, hist, algorithm, W, F, checkpoint_path,
               checkpoint_dir, enc_box: list | None = None) -> dict:
     if not isinstance(hist, History):
         hist = History(hist)
     try:
-        enc = encode(model, hist)
+        with telemetry.span("wgl.encode"):
+            enc = encode(model, hist)
     except EncodingError:
         out = search_host_model(model, hist, witness=True)
         out["analyzer"] = "model"
@@ -1924,20 +1960,24 @@ def analysis_batch_streamed(model, hists: Sequence, chunk: int = 256,
     def launch(group, start):
         encs = []
         idx_map = []
-        for off, hh in enumerate(group):
-            i = start + off
-            if not isinstance(hh, History):
-                hh = History(hh)
-            try:
-                encs.append(encode(model, hh))
-                idx_map.append(i)
-            except EncodingError:
-                out = search_host_model(model, hh, witness=True)
-                out["analyzer"] = "model"
-                results[i] = _witness_op_indices(_search_stats(out))
-                if certify_mod is not None:
-                    certify_mod.attach_wgl(model, hh, None,
-                                           results[i])
+        unencodable = []
+        # one encode span per chunk, not per history
+        with telemetry.span("wgl.encode"):
+            for off, hh in enumerate(group):
+                i = start + off
+                if not isinstance(hh, History):
+                    hh = History(hh)
+                try:
+                    encs.append(encode(model, hh))
+                    idx_map.append(i)
+                except EncodingError:
+                    unencodable.append((i, hh))
+        for i, hh in unencodable:
+            out = search_host_model(model, hh, witness=True)
+            out["analyzer"] = "model"
+            results[i] = _witness_op_indices(_search_stats(out))
+            if certify_mod is not None:
+                certify_mod.attach_wgl(model, hh, None, results[i])
         if not encs:
             return None
         try:

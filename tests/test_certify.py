@@ -363,10 +363,10 @@ class TestSearchExplorer:
         res = wgl.check_batch(encs)
         assert set(res) <= {wgl.VALID, wgl.INVALID, wgl.UNKNOWN}
         c = telemetry.get().counters()
-        assert c["wgl.search.levels"] >= 1
-        assert c["wgl.search.states"] >= 1
+        assert c["profiler.wgl.iterations"] >= 1
+        assert c["profiler.wgl.states"] >= 1
         g = telemetry.get().gauges()
-        assert g["wgl.search.frontier-peak"] >= 1
+        assert g["profiler.wgl.frontier_peak"] >= 1
         recs = [r for r in profiler.get().records()
                 if r["kernel"] == "wgl"]
         assert recs

@@ -135,12 +135,12 @@ def test_web_ui(tmp_path, monkeypatch):
             '{"id": 1, "parent": null, "name": "run", "t0": 0, '
             '"t1": 5000000}\n')
         (d / "metrics.json").write_text(
-            '{"spans": {}, "counters": {"wgl.kernel.launches": 2}, '
+            '{"spans": {}, "counters": {"profiler.wgl.launches": 2}, '
             '"gauges": {}}')
         page = urllib.request.urlopen(
             base + "/telemetry/demo/20260729T000000.0000"
         ).read().decode()
-        assert "run" in page and "wgl.kernel.launches" in page
+        assert "run" in page and "profiler.wgl.launches" in page
         assert "5.0ms" in page
         home = urllib.request.urlopen(base + "/").read().decode()
         assert "/telemetry/demo/" in home

@@ -497,6 +497,26 @@ class TestRegressionGate:
         assert entry["kernels"]["wgl-segmented"]["value"] == 11.5
         ledger.validate_entries([entry | {"ts": 1.0}])
 
+    def test_kernel_lines_read_the_profiler_counters(self, monkeypatch,
+                                                     tmp_path, fresh):
+        """bench's compile share and search stats sum the
+        profiler.<kernel>.* counters over the wgl launch family."""
+        bench = self._gate(monkeypatch, tmp_path, [])
+        telemetry.count("profiler.wgl.compile_ns", 3_000_000)
+        telemetry.count("profiler.wgl-reach.compute_ns", 5_000_000)
+        telemetry.count("profiler.wgl-sharded.d2h_ns", 2_000_000)
+        telemetry.count("profiler.wgl.states", 40)
+        telemetry.count("profiler.wgl-reach.states", 2)
+        telemetry.count("profiler.wgl-reach.dedup_hits", 7)
+        telemetry.gauge_max("profiler.wgl-reach.frontier_peak", 9)
+        share = next(ln for ln in bench._telemetry_lines()
+                     if "compile share" in ln["metric"])
+        assert share["value"] == 0.3
+        headline = {"metric": "m", "value": 1.0, "unit": "ops/s"}
+        search = bench._ledger_entry([], headline)["search"]
+        assert search == {"states_explored": 42, "dedup_hits": 7,
+                          "frontier_peak": 9}
+
 
 def test_bench_exits_nonzero_after_printing_when_a_config_raises(
         monkeypatch, capsys):

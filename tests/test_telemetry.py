@@ -167,10 +167,10 @@ class TestRendering:
     def test_text_and_html_render(self):
         t = telemetry.Telemetry()
         with t.span("run"):
-            t.count("wgl.kernel.compile_ns", 12_000_000)
+            t.count("profiler.wgl.compile_ns", 12_000_000)
             t.gauge("wgl.batch.occupancy", 0.5)
         text = rtel.telemetry_text(t.events(), t.metrics())
-        assert "run" in text and "wgl.kernel.compile_ns" in text
+        assert "run" in text and "profiler.wgl.compile_ns" in text
         assert "12.0ms" in text  # _ns counters render as durations
         html = rtel.telemetry_html("demo", t.events(), t.metrics())
         assert "wgl.batch.occupancy" in html and "<table" in html
@@ -211,8 +211,8 @@ class TestPipeline:
         assert c["interpreter.invoke_ns"] > 0
         # the linearizable checker went through the device kernel
         assert c.get("wgl.batch.histories", 0) >= 1
-        assert c.get("wgl.kernel.launches", 0) >= 1
-        assert c.get("wgl.kernel.iterations", 0) >= 1
+        assert c.get("profiler.wgl.launches", 0) >= 1
+        assert c.get("profiler.wgl.iterations", 0) >= 1
 
         # artifacts land in the store directory and read back
         d = store.path(test)
@@ -307,10 +307,10 @@ class TestKernelMetrics:
         res = wgl.check_batch(encs, W=20, F=24)
         assert (res == wgl.VALID).all()
         c = telemetry.get().counters()
-        assert c["wgl.kernel.compiles"] >= 1
-        assert c["wgl.kernel.compile_ns"] > 0
-        assert c["wgl.kernel.launches"] >= 1
-        assert c["wgl.kernel.iterations"] >= 1
+        assert c["profiler.wgl.compile.miss"] >= 1
+        assert c["profiler.wgl.compile_ns"] > 0
+        assert c["profiler.wgl.launches"] >= 1
+        assert c["profiler.wgl.iterations"] >= 1
         assert c["wgl.batch.histories"] == 4
         assert 0 < c["wgl.batch.entries"] <= c["wgl.batch.slots"]
         g = telemetry.get().gauges()
