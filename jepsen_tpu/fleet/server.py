@@ -293,6 +293,13 @@ class FleetServer:
             except OSError:
                 pass
         self._close_conns()
+        # threads outlive this "death": a WAL compaction in flight ends
+        # before kill() returns, and later ones see _killed (the sink)
+        with self._lock:
+            runs = list(self._runs.values())
+        for rs in runs:
+            with rs.lock:
+                pass
         # scheduler threads are daemons; in-flight items are abandoned
         # exactly as a real SIGKILL would abandon them
         self.scheduler._stop.set()
@@ -320,10 +327,15 @@ class FleetServer:
         path = tckpt.fleet_path(self.base, rs.tenant, rs.run)
 
         def sink(rec: dict) -> None:
-            if not tckpt.try_write(path, rec):
+            # the stream's thread outlives kill(); a killed process
+            # writes nothing more, and a late compaction would rename
+            # the WAL from under a restarted server's append fd
+            if self._killed or not tckpt.try_write(path, rec):
                 return  # counted in ckpt; stale-but-valid file wins
             cut = rec.get("n_ops", 0)
             with rs.lock:
+                if self._killed:
+                    return
                 through = max(
                     (s for s, c in rs.cum_ops.items() if c <= cut),
                     default=0)

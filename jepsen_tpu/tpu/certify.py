@@ -36,13 +36,17 @@ can't be extracted say so honestly (`{"absent": reason}`) — an absent
 certificate is allowed, a validating-but-wrong one never is.
 
 Extraction cost is bounded (a node budget on the order search) and
-priced by bench.py's certificate-overhead line; JEPSEN_TPU_CERTIFY=0
+priced by bench.py's certificate-overhead line. A segmented verdict
+starts its certificate's digest and orders on the host while the
+device still runs its later waves (`Prefetch`); JEPSEN_TPU_CERTIFY=0
 disables extraction entirely (verdicts then carry an honest absent
 marker rather than nothing, so downstream walks stay uniform).
 """
 
 from __future__ import annotations
 
+import collections
+import functools
 import json
 import logging
 import os
@@ -881,7 +885,7 @@ class _Budget(Exception):
 
 
 def _order_search(enc, targets=None, witness: bool = False,
-                  budget: int = SEARCH_BUDGET):
+                  budget: int | None = None):
     """DFS over WGL configurations recording the linearization path —
     the prover-side search that turns the kernel's yes/no (plus
     reach-mask choice data) back into checkable steps. Returns
@@ -889,7 +893,9 @@ def _order_search(enc, targets=None, witness: bool = False,
     reaching the end, in a target state when `targets` given), or
     (False, best) where best = (actions, p, wmask, state) is the
     deepest configuration reached (the witness stub). Raises _Budget
-    past the node budget."""
+    past the node budget (SEARCH_BUDGET unless given)."""
+    if budget is None:
+        budget = SEARCH_BUDGET
     m = enc.m
     if m == 0:
         return True, []
@@ -962,6 +968,89 @@ def _entry_order_json(enc, actions) -> list:
     return [[int(enc.entry_ops[e].index), a] for e, a in actions]
 
 
+def _segment_order(enc, key: tuple) -> list | None:
+    """The certificate's order of entries [lo, hi) from state `start`
+    to state `target`, key = (lo, hi, start, target); None where no
+    such linearization exists. A pure function of the encode and the
+    key, so a prefetched order is the one extraction would compute."""
+    lo, hi, start, target = key
+    seg = enc.segment(lo, hi, init_state=start)
+    ok, actions = _order_search(seg, targets={target})
+    return _entry_order_json(seg, actions) if ok else None
+
+
+class Prefetch:
+    """A valid certificate's host work, done while the chip searches.
+
+    wgl.analysis makes one per verdict that will carry a certificate.
+    check_segmented offers it the reach data after each wave's
+    composition (`offer`) and lets it work between a wave's main-launch
+    dispatch and drain (`work`); attach_wgl then takes the digest and
+    every order it finished, and computes the rest as it would without
+    it. A job that raises is dropped: extraction recomputes it and
+    reaches the same outcome, absent marker included."""
+
+    def __init__(self, hist):
+        self.hist = hist
+        self.enc = None              # set once the history is encoded
+        self.digest: dict | None = None
+        self.orders: dict[tuple, list] = {}
+        self.used = 0                # orders extraction took from here
+        self._jobs: collections.deque = collections.deque([self._digest])
+        self._queued: set = set()
+
+    def _digest(self) -> None:
+        self.digest = history_digest(self.hist)
+
+    def _order(self, key: tuple) -> None:
+        order = _segment_order(self.enc, key)
+        if order is not None:
+            self.orders[key] = order
+
+    def offer(self, cuts, resolved, reaches) -> None:
+        """Queues the order of each segment whose chain endpoints are
+        fixed. The verdict's chain (wgl._reach_chain, built backward
+        from the final state) passes through reaches[i]'s one state
+        wherever that set is a singleton, and everything before such a
+        cut follows from masks already resolved. So the segments before
+        the last singleton cut get the endpoints the final chain will
+        give them."""
+        from .wgl import _reach_chain
+
+        i = max(j for j, r in enumerate(reaches) if r & (r - 1) == 0)
+        if i == 0:
+            return
+        chain = _reach_chain(resolved, reaches[:i + 1], i,
+                             reaches[i].bit_length() - 1)
+        if chain is None:
+            return
+        for j in range(i):
+            key = (int(cuts[j]), int(cuts[j + 1]), chain[j], chain[j + 1])
+            if key not in self._queued:
+                self._queued.add(key)
+                self._jobs.append(functools.partial(self._order, key))
+
+    def work(self, ready) -> None:
+        """Runs queued jobs, one at a time, while ready() is false."""
+        if not self._jobs or ready():
+            return
+        with telemetry.span("certify.prefetch"):
+            while self._jobs and not ready():
+                job = self._jobs.popleft()
+                try:
+                    job()
+                except Exception:  # noqa: BLE001 — extraction redoes it
+                    # and reports its outcome, absent marker included
+                    logger.debug("certificate prefetch job dropped",
+                                 exc_info=True)
+
+    def take(self, key: tuple) -> list | None:
+        order = self.orders.get(key)
+        if order is not None:
+            self.used += 1
+        return order
+
+
 def _witness_from_best(enc, best, model_name: str) -> dict:
     """A stuck-configuration witness from the deepest config the
     search reached: the prefix, the state, and a non-crashed candidate
@@ -998,15 +1087,18 @@ def _witness_from_best(enc, best, model_name: str) -> dict:
     }
 
 
-def wgl_certificate(model, hist, enc, result) -> dict:
+def wgl_certificate(model, hist, enc, result,
+                    prefetch: Prefetch | None = None) -> dict:
     """Builds the certificate for one wgl analysis result. Valid
     verdicts get a per-segment linearization order guided by the
     result's reach/choice chain (`search-chain`, recorded by
     check_segmented) when present; invalid verdicts a replayable
     blocked-frontier witness. Failure to extract returns an honest
-    absent marker, never raises."""
+    absent marker, never raises. `prefetch`: the verdict's Prefetch,
+    whose digest and orders are used where it has them; the
+    certificate is the same with or without it."""
     try:
-        return _wgl_certificate(model, hist, enc, result)
+        return _wgl_certificate(model, hist, enc, result, prefetch)
     except _Budget:
         return absent("search-budget-exceeded")
     except CertificateError as e:
@@ -1016,7 +1108,24 @@ def wgl_certificate(model, hist, enc, result) -> dict:
         return absent(f"extraction-failed: {e!r}")
 
 
-def _wgl_certificate(model, hist, enc, result) -> dict:
+def _chain_segments(enc, chain_info, n: int, prefetch, fault) -> list:
+    """The certificate's first n segments along the search chain, each
+    order taken from the prefetch where it has it; raises
+    CertificateError(fault(j)) where segment j has none."""
+    cuts, chain = chain_info["cuts"], chain_info["chain"]
+    out = []
+    for j in range(n):
+        key = (int(cuts[j]), int(cuts[j + 1]), chain[j], chain[j + 1])
+        order = prefetch.take(key) if prefetch is not None else None
+        if order is None:
+            order = _segment_order(enc, key)
+        if order is None:
+            raise CertificateError(fault(j))
+        out.append({"range": [key[0], key[1]], "order": order})
+    return out
+
+
+def _wgl_certificate(model, hist, enc, result, prefetch=None) -> dict:
     verdict = result.get("valid?")
     if verdict not in (True, False):
         return absent("verdict is indeterminate")
@@ -1027,29 +1136,22 @@ def _wgl_certificate(model, hist, enc, result) -> dict:
     if enc is None:
         return absent("history was not encodable (object-model "
                       "search)")
+    digest = prefetch.digest if prefetch is not None else None
     cert: dict = {"v": VERSION, "kind": "wgl",
                   "verdict": "valid" if verdict else "invalid",
-                  "model": spec, "history": history_digest(hist),
+                  "model": spec,
+                  "history": digest or history_digest(hist),
                   "segments": []}
     chain_info = result.get("search-chain")
     model_name = spec["name"]
     if verdict:
         if chain_info:
-            cuts = chain_info["cuts"]
             chain = chain_info["chain"]
-            for j in range(len(cuts) - 1):
-                seg = enc.segment(cuts[j], cuts[j + 1],
-                                  init_state=chain[j])
-                ok, actions = _order_search(seg,
-                                            targets={chain[j + 1]})
-                if not ok:
-                    raise CertificateError(
-                        f"no linearization of segment {j} from state "
-                        f"{chain[j]} to {chain[j + 1]} — the reach "
-                        "chain lies")
-                cert["segments"].append({
-                    "range": [int(cuts[j]), int(cuts[j + 1])],
-                    "order": _entry_order_json(seg, actions)})
+            cert["segments"] = _chain_segments(
+                enc, chain_info, len(chain_info["cuts"]) - 1, prefetch,
+                lambda j: f"no linearization of segment {j} from state "
+                          f"{chain[j]} to {chain[j + 1]} — the reach "
+                          "chain lies")
         else:
             ok, actions = _order_search(enc)
             if not ok:
@@ -1066,16 +1168,9 @@ def _wgl_certificate(model, hist, enc, result) -> dict:
         cuts = chain_info["cuts"]
         chain = chain_info["chain"]
         k = int(result["failed-segment"])
-        for j in range(k):
-            seg = enc.segment(cuts[j], cuts[j + 1],
-                              init_state=chain[j])
-            ok, actions = _order_search(seg, targets={chain[j + 1]})
-            if not ok:
-                raise CertificateError(
-                    f"no linearization of pre-witness segment {j}")
-            cert["segments"].append({
-                "range": [int(cuts[j]), int(cuts[j + 1])],
-                "order": _entry_order_json(seg, actions)})
+        cert["segments"] = _chain_segments(
+            enc, chain_info, k, prefetch,
+            lambda j: f"no linearization of pre-witness segment {j}")
         wseg = enc.segment(cuts[k], cuts[k + 1], init_state=chain[k])
         found, best = _order_search(wseg, witness=True)
         if found:
@@ -1092,11 +1187,15 @@ def _wgl_certificate(model, hist, enc, result) -> dict:
     return cert
 
 
-def attach_wgl(model, hist, enc, result) -> dict:
+def attach_wgl(model, hist, enc, result,
+               prefetch: Prefetch | None = None) -> dict:
     """Attaches a certificate to a wgl analysis result (checker entry
     points call this; raw bench/kernel paths don't). Disabled
     extraction still leaves an honest absent marker so result walks
-    stay uniform."""
+    stay uniform. `prefetch`: the verdict's Prefetch (wgl.analysis),
+    or None. Counts the segment orders of a certificate made
+    (`certify.segments`) and how many of them the prefetch had ready
+    (`certify.segments.prefetched`)."""
     if not isinstance(result, dict):
         return result
     if not enabled():
@@ -1107,10 +1206,15 @@ def attach_wgl(model, hist, enc, result) -> dict:
     # separately from device compute (flightrec.kernel_phases joins
     # this span against the launch window)
     with telemetry.span("certify.attach"):
-        cert = wgl_certificate(model, hist, enc, result)
+        cert = wgl_certificate(model, hist, enc, result, prefetch)
     result["certificate"] = cert
-    telemetry.count("certify.absent" if "absent" in cert
-                    else "certify.extracted")
+    if "absent" in cert:
+        telemetry.count("certify.absent")
+    else:
+        telemetry.count("certify.extracted")
+        telemetry.count("certify.segments", len(cert["segments"]))
+        telemetry.count("certify.segments.prefetched",
+                        prefetch.used if prefetch is not None else 0)
     return result
 
 

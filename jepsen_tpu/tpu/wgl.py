@@ -1260,16 +1260,25 @@ def _screen_wave(enc: Encoded, segs, cuts, vcuts, lo: int, hi: int,
     return rows
 
 
+def _launch_ready(out) -> bool:
+    """Whether a dispatched launch has finished on the device (outputs
+    without is_ready, a mocked launch's numpy arrays, are ready)."""
+    is_ready = getattr(out[0], "is_ready", None)
+    return is_ready is None or bool(is_ready())
+
+
 def _resolve_wave(enc: Encoded, segs, cuts, vcuts, lo: int, hi: int,
                   S: int, W: int, F: int, prefix_screen: int,
-                  resolved: dict, pad_to: int | None = None) -> None:
+                  resolved: dict, pad_to: int | None = None,
+                  prefetch=None) -> None:
     """Resolves every unresolved (segment, start-state) reach mask for
     segments [lo, hi): the device prefix screen first (rows whose
     time-complete prefix proves mask 0 never reach the main launch),
     then ONE batched reach launch over the survivors. Device failures
     leave rows at None for the caller's lazy host floor. pad_to pads
     the wave's packed batch with empty segments so wave launches
-    bucket to a fixed set of compile shapes."""
+    bucket to a fixed set of compile shapes. prefetch (a
+    certify.Prefetch) works on the host while the main launch runs."""
     if prefix_screen:
         with telemetry.span("wgl.screen"):
             rows = _screen_wave(enc, segs, cuts, vcuts, lo, hi, S, W, F,
@@ -1291,8 +1300,10 @@ def _resolve_wave(enc: Encoded, segs, cuts, vcuts, lo: int, hi: int,
     try:
         pb = PackedBatch(wave_segs)
         launch_rows = [(k - lo, s) for k, s in rows]
-        out, unk = _drain(_launch(pb, launch_rows, W, F, reach=True),
-                          reach=True)
+        dev = _launch(pb, launch_rows, W, F, reach=True)
+        if prefetch is not None:
+            prefetch.work(lambda: _launch_ready(dev))
+        out, unk = _drain(dev, reach=True)
         out = out[:len(launch_rows)]
         unk = unk[:len(launch_rows)]
         for i, (k, s) in enumerate(rows):
@@ -1310,7 +1321,8 @@ def check_segmented(enc: Encoded, target_len: int | None = None,
                     prefix_screen: int = 96,
                     checkpoint_path=None,
                     checkpoint_dir=None,
-                    early_exit: bool | None = None) -> dict | None:
+                    early_exit: bool | None = None,
+                    prefetch=None) -> dict | None:
     """Checks one long history by cutting it into segments, computing
     per-(segment, start-state) final-state reachability in ONE batched
     device launch, and composing reachability masks across segments.
@@ -1348,6 +1360,11 @@ def check_segmented(enc: Encoded, target_len: int | None = None,
     masks, witnesses and certificates are identical either way — a
     wave resolves exactly the masks the single launch would have, and
     composition stops at the same failed segment.
+
+    prefetch: wgl.analysis's certify.Prefetch when the verdict will
+    carry a certificate, else None. It is offered the reach data after
+    each wave's composition and works on the host while a wave's main
+    launch runs; the verdict is the same either way.
 
     Stage spans: `wgl.segment` (cut points and segments), then per
     wave `wgl.screen` and `wgl.compose` (the composition, with its
@@ -1412,7 +1429,8 @@ def check_segmented(enc: Encoded, target_len: int | None = None,
         _resolve_wave(enc, segs, cuts, vcuts, lo, hi, S, W, F,
                       prefix_screen, resolved,
                       pad_to=(_next_pow2(hi - lo)
-                              if len(waves) > 1 else None))
+                              if len(waves) > 1 else None),
+                      prefetch=prefetch)
         if ckpt is not None:
             ckpt.save(resolved)
         with telemetry.span("wgl.compose"):
@@ -1435,6 +1453,8 @@ def check_segmented(enc: Encoded, target_len: int | None = None,
                     break
                 reach = nreach
                 reaches.append(reach)
+        if prefetch is not None:
+            prefetch.offer(cuts, resolved, reaches)
         if failed_k is not None:
             if hi < K:
                 # the early-exit payoff: segments past the witness's
@@ -1806,19 +1826,27 @@ def analysis(model, hist, algorithm: str = "tpu", W: int | None = None,
 
     The verdict is one `wgl.analysis` span (its stages nest in it:
     `wgl.encode`, then check_segmented's); certificate extraction
-    follows it as `certify.attach`."""
+    follows it as `certify.attach`. A segmented verdict's certificate
+    is begun inside it, while the chip runs a wave's main launch
+    (`certify.prefetch` spans; certify.Prefetch)."""
+    certify_mod = prefetch = None
+    if certify:
+        from . import certify as certify_mod
+
+        if certify_mod.enabled():
+            prefetch = certify_mod.Prefetch(hist)
     with _ladder_scope() as steps:
         enc_box: list = [None]
         with telemetry.span("wgl.analysis"):
             out = _analysis(model, hist, algorithm, W, F,
-                            checkpoint_path, checkpoint_dir, enc_box)
+                            checkpoint_path, checkpoint_dir, enc_box,
+                            prefetch)
             if steps:
                 out["degradation"] = list(steps)
             _search_stats(out)
-        if certify:
-            from . import certify as certify_mod
-
-            certify_mod.attach_wgl(model, hist, enc_box[0], out)
+        if certify_mod is not None:
+            certify_mod.attach_wgl(model, hist, enc_box[0], out,
+                                   prefetch)
         return out
 
 
@@ -1884,7 +1912,8 @@ def _analysis_extend(model, hist: History, store_path, stride: int,
 
 
 def _analysis(model, hist, algorithm, W, F, checkpoint_path,
-              checkpoint_dir, enc_box: list | None = None) -> dict:
+              checkpoint_dir, enc_box: list | None = None,
+              prefetch=None) -> dict:
     if not isinstance(hist, History):
         hist = History(hist)
     try:
@@ -1896,6 +1925,8 @@ def _analysis(model, hist, algorithm, W, F, checkpoint_path,
         return _witness_op_indices(out)
     if enc_box is not None:
         enc_box[0] = enc  # certificate extraction reuses the encode
+    if prefetch is not None:
+        prefetch.enc = enc
 
     if algorithm == "model":
         out = search_host_model(model, hist, witness=True)
@@ -1917,7 +1948,8 @@ def _analysis(model, hist, algorithm, W, F, checkpoint_path,
             seg_kw["checkpoint_path"] = checkpoint_path
         if checkpoint_dir is not None:
             seg_kw["checkpoint_dir"] = checkpoint_dir
-        seg = check_segmented(enc, witness=True, **seg_kw)
+        seg = check_segmented(enc, witness=True, prefetch=prefetch,
+                              **seg_kw)
         if seg is not None:
             seg["analyzer"] = "tpu-segmented"
             return _witness_op_indices(seg)
