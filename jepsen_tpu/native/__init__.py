@@ -110,6 +110,11 @@ def jlog() -> ctypes.CDLL | None:
                 ctypes.POINTER(ctypes.c_int64), ctypes.c_int64,
                 ctypes.POINTER(ctypes.c_int64),
                 ctypes.POINTER(ctypes.c_int64), ctypes.c_int64]
+            lib.jt_topo_order.restype = ctypes.c_int64
+            lib.jt_topo_order.argtypes = [
+                ctypes.c_int64, ctypes.POINTER(ctypes.c_int64),
+                ctypes.POINTER(ctypes.c_int64), ctypes.c_int64,
+                ctypes.POINTER(ctypes.c_int64)]
             _lib = lib
         except Exception:  # noqa: BLE001 — never break the store
             logger.exception("loading native jlog codec failed")
@@ -279,3 +284,24 @@ def realtime_edges(inv, comp):
         if m < 0:
             raise RuntimeError(f"native order sweep failed ({m})")
         return src[:m].copy(), dst[:m].copy()
+
+
+def topo_order(n: int, src, dst):
+    """The Kahn order (order.c's jt_topo_order) of the graph on nodes
+    0..n-1 with edges src[i] -> dst[i], as an int64 array: every node
+    when the graph is acyclic, fewer when some node lies on or behind a
+    cycle. Raises RuntimeError if the codec is unavailable."""
+    import numpy as np
+
+    lib = jlog()
+    if lib is None or not hasattr(lib, "jt_topo_order"):
+        raise RuntimeError("native topological order unavailable")
+    src = np.ascontiguousarray(src, dtype=np.int64)
+    dst = np.ascontiguousarray(dst, dtype=np.int64)
+    out = np.empty(max(n, 1), dtype=np.int64)
+    p = ctypes.POINTER(ctypes.c_int64)
+    m = lib.jt_topo_order(n, src.ctypes.data_as(p), dst.ctypes.data_as(p),
+                          len(src), out.ctypes.data_as(p))
+    if m < 0:
+        raise RuntimeError(f"native topological order failed ({m})")
+    return out[:m].copy()

@@ -1,4 +1,5 @@
-/* Realtime-order edge sweep for the elle engines.
+/* Realtime-order edge sweep for the elle engines, and the topological
+ * order of a verdict's dependency graph (jt_topo_order, below).
  *
  * The reference's realtime relation comes from elle's
  * process/realtime graphs (jepsen/src/jepsen/tests/cycle/append.clj
@@ -93,6 +94,57 @@ int64_t jt_realtime_edges(const int64_t *inv, const int64_t *comp,
     free(events);
     free(frontier);
     return m;
+}
+
+/* Topological order of a dependency graph: one Kahn pass over a CSR
+ * of the edge arrays (the certificate of a valid elle verdict is such
+ * an order of the verdict's own graph). Edges are bucketed by source
+ * in input order; the queue starts with the sources in id order and
+ * is first-in first-out, so the order is a pure function of (n, src,
+ * dst) and equals the Python fallback's (tpu/elle.py topo_order).
+ * Writes the order into out[0..n) and returns its length: n when the
+ * graph is acyclic, less when some node lies on or behind a cycle; -2
+ * on allocation failure. */
+int64_t jt_topo_order(int64_t n, const int64_t *src, const int64_t *dst,
+                      int64_t m, int64_t *out) {
+    if (n <= 0)
+        return 0;
+    int64_t *start = (int64_t *)calloc((size_t)n + 1, sizeof(int64_t));
+    int64_t *fill = (int64_t *)malloc(sizeof(int64_t) * (size_t)n);
+    int64_t *indeg = (int64_t *)calloc((size_t)n, sizeof(int64_t));
+    int64_t *adj = (int64_t *)malloc(sizeof(int64_t) * (size_t)(m + 1));
+    if (!start || !fill || !indeg || !adj) {
+        free(start);
+        free(fill);
+        free(indeg);
+        free(adj);
+        return -2;
+    }
+    for (int64_t e = 0; e < m; e++) {
+        start[src[e] + 1]++;
+        indeg[dst[e]]++;
+    }
+    for (int64_t v = 0; v < n; v++) {
+        start[v + 1] += start[v];
+        fill[v] = start[v];
+    }
+    for (int64_t e = 0; e < m; e++)
+        adj[fill[src[e]]++] = dst[e];
+    int64_t head = 0, tail = 0;
+    for (int64_t v = 0; v < n; v++)
+        if (indeg[v] == 0)
+            out[tail++] = v;
+    while (head < tail) {
+        int64_t u = out[head++];
+        for (int64_t j = start[u]; j < start[u + 1]; j++)
+            if (--indeg[adj[j]] == 0)
+                out[tail++] = adj[j];
+    }
+    free(start);
+    free(fill);
+    free(indeg);
+    free(adj);
+    return tail;
 }
 
 #ifdef __cplusplus

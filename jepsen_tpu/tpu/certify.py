@@ -17,9 +17,14 @@ luck in a test suite:
                 schema: a replayable prefix reaching a concrete stuck
                 configuration plus the pending op that cannot take
                 effect there.
-  valid elle    a serialization order over the committed txns, checked
-                against the independently-derivable constraint set
-                (session order, realtime order, read-from precedence).
+  valid elle    a serialization: the device engine's list-append
+                verdicts carry the committed txns and the :info txns
+                that took effect in an order whose replay under
+                list-append semantics gives every committed read, with
+                session and realtime order kept. The host engine and
+                rw-register carry an order of the committed txns checked
+                against session order, realtime order and read-from
+                precedence.
   invalid elle  the witnessing cycle's edges, each justified by the
                 concrete mops that induce the ww/wr/rw dependency (or a
                 justified non-cycle anomaly: aborted read, duplicate
@@ -673,6 +678,9 @@ def _validate_elle(hist, cert) -> None:
         raise CertificateError("invalid verdict with neither cycle "
                                "nor anomaly evidence")
     if verdict == "valid":
+        if "serialization" in cert:
+            _validate_serialization(cert["serialization"], txns, family)
+            return
         order = cert.get("topo-order")
         if not isinstance(order, list):
             raise CertificateError("valid verdict without a "
@@ -714,6 +722,62 @@ def _validate_elle(hist, cert) -> None:
                             f"{ws[0]}")
         return
     raise CertificateError(f"unknown elle verdict {verdict!r}")
+
+
+def _hkey(k):
+    return k if isinstance(k, (int, str)) else json.dumps(
+        k, sort_keys=True, default=repr)
+
+
+def _validate_serialization(order, txns: dict, family: str) -> None:
+    """A valid list-append verdict's serialization, replayed: each
+    committed txn once, the rest :info txns, and in that order every
+    committed read returns exactly the appends to its key so far. A
+    txn completed before another was invoked precedes it; an :info
+    txn's completion is unknown, so nothing must follow it by
+    realtime. Each process's committed txns keep their invocation
+    order (a process's next txn may precede an :info txn that had not
+    yet taken effect)."""
+    if family != "list-append" or not isinstance(order, list):
+        raise CertificateError("a serialization is a list-append order")
+    if len(set(order)) != len(order):
+        raise CertificateError("serialization repeats a txn")
+    n_ok = 0
+    for i in order:
+        t = txns.get(i)
+        if t is None or t["type"] == h.FAIL:
+            raise CertificateError(f"serialization entry {i!r} is not "
+                                   "a committed or indeterminate txn")
+        n_ok += t["type"] == h.OK
+    if n_ok != sum(1 for t in txns.values() if t["type"] == h.OK):
+        raise CertificateError("serialization leaves out committed txns")
+    lists: dict = {}
+    max_inv = -1
+    last_by_proc: dict = {}
+    for i in order:
+        t = txns[i]
+        ok = t["type"] == h.OK
+        if ok and t["ret_pos"] < max_inv:
+            raise CertificateError(
+                f"serialization violates realtime order at txn {i}")
+        max_inv = max(max_inv, t["inv_pos"])
+        if ok:
+            prev = last_by_proc.get(t["process"])
+            if prev is not None and t["inv_pos"] < prev:
+                raise CertificateError(
+                    f"serialization violates session order at txn {i}")
+            last_by_proc[t["process"]] = t["inv_pos"]
+        for m in t["mops"]:
+            if not isinstance(m, list) or len(m) < 3:
+                continue
+            cur = lists.setdefault(_hkey(m[1]), [])
+            if m[0] == "append":
+                cur.append(m[2])
+            elif m[0] == "r" and ok and m[2] is not None \
+                    and m[2] != cur:
+                raise CertificateError(
+                    f"serialization does not replay: txn {i} read "
+                    f"{m[2]!r} on {m[1]!r}, the order gives {cur!r}")
 
 
 def _validate_elle_anomaly(anom: dict, txns: dict, family: str
@@ -791,9 +855,9 @@ def validate_schema(cert) -> None:
         if cert.get("family") not in ("list-append", "rw-register"):
             raise CertificateError("elle certificate without a family")
         if cert["verdict"] == "valid" and not isinstance(
-                cert.get("topo-order"), list):
+                cert.get("serialization", cert.get("topo-order")), list):
             raise CertificateError("valid elle certificate without a "
-                                   "topo-order")
+                                   "serialization or topo-order")
 
 
 def validate(hist, cert, digest: dict | None = None) -> None:
@@ -1397,13 +1461,15 @@ def _topo_order(txns: dict, family: str) -> list[int] | None:
     return out
 
 
-def elle_certificate(hist, result, family: str) -> dict:
+def elle_certificate(hist, result, family: str, graph=None) -> dict:
     """Builds the certificate for an elle check result (either
     engine): a justified cycle (or G1a/duplicate evidence) for invalid
-    verdicts, a constraint-checked serialization order for valid ones.
-    Never raises — unprovable results go absent."""
+    verdicts; for valid ones a serialization that replays where
+    `graph`, the device engine's list-append analysis, is given, else a
+    constraint-checked order of the committed txns. Never raises —
+    unprovable results go absent."""
     try:
-        return _elle_certificate(hist, result, family)
+        return _elle_certificate(hist, result, family, graph)
     except CertificateError as e:
         return absent(str(e))
     except Exception as e:  # noqa: BLE001 — extraction is best-effort
@@ -1411,7 +1477,7 @@ def elle_certificate(hist, result, family: str) -> dict:
         return absent(f"extraction-failed: {e!r}")
 
 
-def _elle_certificate(hist, result, family: str) -> dict:
+def _elle_certificate(hist, result, family: str, graph=None) -> dict:
     if not isinstance(hist, History):
         hist = History(hist)
     verdict = result.get("valid?")
@@ -1420,6 +1486,14 @@ def _elle_certificate(hist, result, family: str) -> dict:
     cert: dict = {"v": VERSION, "kind": "elle", "family": family,
                   "verdict": "valid" if verdict else "invalid",
                   "history": history_digest(hist)}
+    if verdict and graph is not None and family == "list-append":
+        rows = graph.serialization()
+        if rows is None:
+            return absent("the dependency graph, with appends no read "
+                          "observed after their key's reads, has a "
+                          "cycle: no serialization")
+        cert["serialization"] = graph.invocation_indices(rows)
+        return cert
     txns = _collect_txns(hist)
     if verdict:
         order = _topo_order(txns, family)
@@ -1474,16 +1548,18 @@ def _elle_certificate(hist, result, family: str) -> dict:
                   f"result (classes: {sorted(anomalies)})")
 
 
-def attach_elle(hist, result, family: str) -> dict:
+def attach_elle(hist, result, family: str, graph=None) -> dict:
     """Attaches a certificate to an elle check result (the checker
-    wrappers opt in via opts['certify']; raw bench calls don't)."""
+    wrappers opt in via opts['certify']; raw bench calls don't).
+    `graph`: the device engine's list-append analysis, or None."""
     if not isinstance(result, dict):
         return result
     if not enabled():
         result["certificate"] = absent("extraction disabled "
                                        "(JEPSEN_TPU_CERTIFY=0)")
         return result
-    cert = elle_certificate(hist, result, family)
+    with telemetry.span("certify.attach"):
+        cert = elle_certificate(hist, result, family, graph)
     result["certificate"] = cert
     telemetry.count("certify.absent" if "absent" in cert
                     else "certify.extracted")

@@ -308,18 +308,61 @@ def order_edges_from_arrays(ids, inv, comp, procid):
     p_src = ids[order[:-1][same]]
     p_dst = ids[order[1:][same]]
     # realtime order: completion-frontier sweep
-    try:
-        from .. import native
-
-        r_src_i, r_dst_i = native.realtime_edges(inv, comp)
-    except RuntimeError:
-        r_src_i, r_dst_i = _realtime_edges_arrays_py(inv, comp)
+    r_src_i, r_dst_i = realtime_edges_arrays(inv, comp)
     r_src, r_dst = ids[r_src_i], ids[r_dst_i]
     src = np.concatenate([p_src, r_src])
     dst = np.concatenate([p_dst, r_dst])
     ty = np.concatenate([np.full(len(p_src), PROC, dtype=np.int64),
                          np.full(len(r_src), RT, dtype=np.int64)])
     return src, dst, ty
+
+
+def realtime_edges_arrays(inv, comp):
+    """Reduced realtime edges over dense row indices: the C sweep
+    (native/order.c), or its Python reference without a toolchain."""
+    from .. import native
+
+    try:
+        return native.realtime_edges(inv, comp)
+    except RuntimeError:
+        return _realtime_edges_arrays_py(inv, comp)
+
+
+def topo_order(n: int, src, dst) -> np.ndarray:
+    """Kahn order of the graph on rows 0..n-1 (native/order.c's
+    jt_topo_order, or its Python reference without a toolchain): all n
+    rows when the graph is acyclic, fewer when some row lies on or
+    behind a cycle."""
+    from .. import native
+
+    try:
+        return native.topo_order(n, src, dst)
+    except RuntimeError:
+        return _topo_order_py(n, src, dst)
+
+
+def _topo_order_py(n: int, src, dst) -> np.ndarray:
+    """The C pass's reference semantics: edges bucketed by source in
+    input order, a first-in first-out queue seeded with the sources in
+    row order."""
+    src = np.asarray(src, dtype=np.int64)
+    dst = np.asarray(dst, dtype=np.int64)
+    by_src = np.argsort(src, kind="stable")
+    start = np.searchsorted(src[by_src], np.arange(n + 1))
+    adj = dst[by_src].tolist()
+    start = start.tolist()
+    indeg = np.bincount(dst, minlength=n).tolist() if n else []
+    out = [v for v in range(n) if indeg[v] == 0]
+    head = 0
+    while head < len(out):
+        u = out[head]
+        head += 1
+        for j in range(start[u], start[u + 1]):
+            v = adj[j]
+            indeg[v] -= 1
+            if indeg[v] == 0:
+                out.append(v)
+    return np.asarray(out, dtype=np.int64)
 
 
 def _realtime_edges_arrays_py(inv, comp):
@@ -553,15 +596,18 @@ def _with_search(result: dict, key_edges: dict | None = None) -> dict:
 
 
 def _finish(result: dict, hist, family: str,
-            opts: dict | None, key_edges: dict | None = None) -> dict:
+            opts: dict | None, key_edges: dict | None = None,
+            graph=None) -> dict:
     """Shared tail of both public checks: search stats always, a
     verdict certificate when the caller opted in (checker wrappers
-    pass opts['certify']; raw bench calls don't pay for proofs)."""
+    pass opts['certify']; raw bench calls don't pay for proofs).
+    `graph`: the device engine's list-append analysis, whose dependency
+    graph orders a valid verdict's certificate."""
     _with_search(result, key_edges)
     if (opts or {}).get("certify"):
         from . import certify as certify_mod
 
-        certify_mod.attach_elle(hist, result, family)
+        certify_mod.attach_elle(hist, result, family, graph=graph)
     return result
 
 
@@ -598,17 +644,28 @@ def check_list_append(hist, opts: dict | None = None) -> dict:
     if not isinstance(hist, History):
         hist = History(hist)
     engine = (opts or {}).get("engine", "auto")
+    with telemetry.span("elle.analysis"):
+        out, key_edges, graph = _list_append_verdict(hist, engine)
+    return _finish(out, hist, "list-append", opts, key_edges, graph)
+
+
+def _list_append_verdict(hist: History, engine: str):
+    """(result, per-key edge counts or None, the device analysis or
+    None): check_list_append's verdict, before its certificate."""
     degraded = None
     if engine == "device" or (engine == "auto"
                               and len(hist) >= _DEVICE_MIN_OPS):
         from . import elle_device
         try:
-            return _finish(_with_classes(annotate_op_indices(
-                elle_device.check_list_append_device(hist), hist),
-                CHECKED_APPEND), hist, "list-append", opts)
+            res, a = elle_device.analyze_list_append(hist)
+            return (_with_classes(annotate_op_indices(res, hist),
+                                  CHECKED_APPEND), None, a)
         except elle_device.Unvectorizable:
             if engine == "device":
                 raise
+            # a history the device engine cannot intern: the host
+            # engine decides it, and a run shows the device path skipped
+            telemetry.count("elle.host-engine")
         except Exception as e:  # noqa: BLE001 — device ladder
             degraded = _degrade_to_host("list-append", e)
     a = AppendAnalysis(hist)
@@ -626,9 +683,8 @@ def check_list_append(hist, opts: dict | None = None) -> dict:
     }
     if degraded:
         out["degradation"] = degraded
-    return _finish(_with_classes(annotate_op_indices(out, hist),
-                                 CHECKED_APPEND),
-                   hist, "list-append", opts, a.key_edges)
+    return (_with_classes(annotate_op_indices(out, hist), CHECKED_APPEND),
+            a.key_edges, None)
 
 
 def check_rw_register(hist, opts: dict | None = None) -> dict:

@@ -34,7 +34,8 @@ from ..history import History
 from . import profiler
 from . import scc as scc_mod
 from .elle import (EDGE_NAMES, PROC, RT, RW, WR, WW, Txn, _classify,
-                   _find_cycle, collect, order_edges_from_arrays)
+                   _find_cycle, collect, order_edges_from_arrays,
+                   realtime_edges_arrays, topo_order)
 
 _TYPE_OK, _TYPE_INFO, _TYPE_FAIL = 0, 1, 2
 _T_CODE = {h.OK: _TYPE_OK, h.INFO: _TYPE_INFO, h.FAIL: _TYPE_FAIL}
@@ -201,6 +202,23 @@ def _pack(keys: np.ndarray, vals: np.ndarray) -> np.ndarray:
     return (keys << _VAL_BITS) | vals
 
 
+def _pairs_by_key(a_key, b_key, b_val):
+    """(a row, b_val) for every pair of an a row and a b row with the
+    same key: a rows in order, each with the b rows of its key in
+    theirs."""
+    empty = np.empty(0, dtype=np.int64)
+    if not len(a_key) or not len(b_key):
+        return empty, empty
+    ob = np.argsort(b_key, kind="stable")
+    bk, bv = b_key[ob], b_val[ob]
+    lo = np.searchsorted(bk, a_key, "left")
+    reps = np.searchsorted(bk, a_key, "right") - lo
+    base = np.repeat(lo, reps)
+    step = np.arange(int(reps.sum())) - np.repeat(np.cumsum(reps) - reps,
+                                                  reps)
+    return np.repeat(np.arange(len(a_key)), reps), bv[base + step]
+
+
 class DeviceAppendAnalysis:
     """Mirrors elle.AppendAnalysis over Flat arrays. Flattening runs
     through the C pass (native.elle_flatten) when available; txn/op
@@ -211,29 +229,33 @@ class DeviceAppendAnalysis:
 
     def __init__(self, hist: History, device: bool = True):
         self.device = device
-        self._ops = list(hist)
         self.txns: list[Txn] | None = None
         self.flat = self._flatten(hist)
         self.anomalies: dict[str, list] = defaultdict(list)
-        self._resolve_writers()
-        self._spines()
-        self._read_anomalies()
-        (self.edge_src, self.edge_dst, self.edge_ty,
-         self.edge_key) = self._edges()
+        with telemetry.span("elle.edges"):
+            self._resolve_writers()
+            self._spines()
+            self._read_anomalies()
+            data = self._data_edges()
+        with telemetry.span("elle.order-edges"):
+            (self.edge_src, self.edge_dst, self.edge_ty,
+             self.edge_key) = self._with_order_edges(*data)
 
     def _flatten(self, hist: History):
         from .. import native
 
-        try:
-            arrs, keys = native.elle_flatten(self._ops, self._KIND)
-            telemetry.count("elle.flatten.native")
-            return self._FLAT_CLS.from_native(self._ops, arrs, keys)
-        except native.NotVectorizable as e:
-            raise Unvectorizable(str(e)) from e
-        except RuntimeError:
-            telemetry.count("elle.flatten.python")
-            self.txns = collect(hist)
-            return self._FLAT_CLS(self.txns)
+        with telemetry.span("elle.flatten"):
+            self._ops = list(hist)
+            try:
+                arrs, keys = native.elle_flatten(self._ops, self._KIND)
+                telemetry.count("elle.flatten.native")
+                return self._FLAT_CLS.from_native(self._ops, arrs, keys)
+            except native.NotVectorizable as e:
+                raise Unvectorizable(str(e)) from e
+            except RuntimeError:
+                telemetry.count("elle.flatten.python")
+                self.txns = collect(hist)
+                return self._FLAT_CLS(self.txns)
 
     def _op(self, ti: int):
         """The witness op for txn row ti (lazy: no Txn objects on the
@@ -245,6 +267,58 @@ class DeviceAppendAnalysis:
     @property
     def n(self) -> int:
         return self.flat.n
+
+    # -- the certificate of a valid verdict ------------------------------
+
+    def serialization(self) -> np.ndarray | None:
+        """Txn rows in an order that serializes a valid verdict's
+        history: the committed txns and the :info txns whose appends a
+        committed read observed, in a topological order of the
+        verdict's own dependency graph (ww, wr, rw, process, realtime)
+        plus the two constraints that graph leaves free. An append that
+        no read observed follows every read that saw its key's whole
+        version order, and an :info txn follows every txn that completed
+        before it was invoked. None when the graph so constrained has a
+        cycle: then no such order exists."""
+        f = self.flat
+        took = f.t_type == _TYPE_OK
+        seen = self.re_pid[self.re_pid >= 0] if len(self.re_pid) else \
+            np.empty(0, dtype=np.int64)
+        if seen.size and len(self.w_txn):
+            wt = self.w_txn[seen]
+            wt = wt[wt >= 0]
+            took[wt[f.t_type[wt] == _TYPE_INFO]] = True
+        srcs, dsts = [self.edge_src], [self.edge_dst]
+        # appends no read observed, after the reads of the whole spine
+        on_spine = np.zeros(len(self.pair_codes), dtype=bool)
+        on_spine[self.sp_pid[self.sp_pid >= 0]] = True
+        unseen = took[f.ap_txn] & ~on_spine[self.ap_pid]
+        whole = f.rd_len == self.spine_len[f.rd_key]
+        ia, u_txn = _pairs_by_key(f.rd_key[whole], f.ap_key[unseen],
+                                  f.ap_txn[unseen])
+        r_txn = f.rd_txn[whole][ia]
+        keep = r_txn != u_txn
+        srcs.append(r_txn[keep])
+        dsts.append(u_txn[keep])
+        # realtime into the :info txns that took effect (their own
+        # completion is unknown: they never precede by realtime)
+        rows = np.flatnonzero(took)
+        info = f.t_type[rows] == _TYPE_INFO
+        if info.any():
+            comp = np.where(info, np.int64(1 << 60), f.t_comp[rows])
+            r_src, r_dst = realtime_edges_arrays(f.t_inv[rows], comp)
+            into = info[r_dst]
+            srcs.append(rows[r_src[into]])
+            dsts.append(rows[r_dst[into]])
+        order = topo_order(f.n, np.concatenate(srcs), np.concatenate(dsts))
+        if len(order) < f.n:
+            return None
+        return order[took[order]]
+
+    def invocation_indices(self, rows) -> list[int]:
+        """The history index of each txn row's invocation."""
+        ops = self._ops
+        return [ops[p].index for p in self.flat.t_inv[rows].tolist()]
 
     # -- writers -----------------------------------------------------------
 
@@ -456,7 +530,9 @@ class DeviceAppendAnalysis:
 
     # -- edges -------------------------------------------------------------
 
-    def _edges(self):
+    def _data_edges(self):
+        """The ww, wr and rw edges, as per-kind (src, dst, type, key)
+        array lists."""
         f = self.flat
         srcs: list[np.ndarray] = []
         dsts: list[np.ndarray] = []
@@ -533,21 +609,17 @@ class DeviceAppendAnalysis:
                 np.flatnonzero(first_w >= 0)])
             tk_txn = np.concatenate([
                 self.wk_txn[off_spine], first_w[first_w >= 0]])
-            t_order = np.argsort(tk_key, kind="stable")
-            tk_key, tk_txn = tk_key[t_order], tk_txn[t_order]
-            cnt = np.bincount(tk_key, minlength=K)
-            off = np.concatenate([[0], np.cumsum(cnt)])[:-1]
             ek = f.rd_key[ez]
-            reps = cnt[ek]
-            er_src = np.repeat(f.rd_txn[ez], reps)
-            base = np.repeat(off[ek], reps)
-            step = np.arange(reps.sum()) - np.repeat(
-                np.concatenate([[0], np.cumsum(reps)])[:-1], reps)
-            er_dst = tk_txn[base + step]
+            ia, er_dst = _pairs_by_key(ek, tk_key, tk_txn)
+            er_src = f.rd_txn[ez][ia]
             keep = er_src != er_dst
-            emit(er_src[keep], er_dst[keep], RW,
-                 np.repeat(ek, reps)[keep])
-        # session order + realtime: the host engine's sweep, shared
+            emit(er_src[keep], er_dst[keep], RW, ek[ia][keep])
+        return srcs, dsts, tys, kks
+
+    def _with_order_edges(self, srcs, dsts, tys, kks):
+        """The graph: the data edges, the session order and the
+        realtime order (the host engine's sweep, shared), deduplicated
+        in first-seen order."""
         comm = np.flatnonzero(self.flat.t_type == _TYPE_OK)
         if comm.size:
             fl = self.flat
@@ -606,9 +678,19 @@ def cycle_anomalies_arrays(n: int, src, dst, ty, txns,
     # Early exit: subset edges are subsets of the full graph, so a
     # clean full graph proves every graded subset clean too — valid
     # histories cost ONE device SCC instead of five.
-    full = scc_mod.scc(n, src, dst, device=device, ekey=ekey)
+    with telemetry.span("elle.scc"):
+        full = scc_mod.scc(n, src, dst, device=device, ekey=ekey)
     if not scc_mod.nontrivial_from_labels(full):
         return out
+    with telemetry.span("elle.cycles"):
+        _graded_cycles(out, n, src, dst, ty, full, op_of, device, ekey)
+    return out
+
+
+def _graded_cycles(out: dict, n: int, src, dst, ty, full, op_of,
+                   device: bool, ekey) -> None:
+    """The SCCs of each graded subset, a witness cycle per new
+    component, classified."""
     seen: set = set()
     for sub in _SUBSETS:
         # boolean mask over ONE shared edge array: every subset reuses
@@ -618,10 +700,12 @@ def cycle_anomalies_arrays(n: int, src, dst, ty, txns,
         if not mask.any():
             continue
         if sub == _SUBSETS[-1]:
-            groups = scc_mod.nontrivial_from_labels(full)
+            labels = full
         else:
-            groups = scc_mod.nontrivial_sccs(n, src, dst, emask=mask,
-                                             device=device, ekey=ekey)
+            with telemetry.span("elle.scc"):
+                labels = scc_mod.scc(n, src, dst, emask=mask,
+                                     device=device, ekey=ekey)
+        groups = scc_mod.nontrivial_from_labels(labels)
         for members in groups:
             key = frozenset(int(x) for x in members)
             if key in seen:
@@ -638,24 +722,27 @@ def cycle_anomalies_arrays(n: int, src, dst, ty, txns,
                 "cycle": [op_of(a) for a, _b, _c in cycle],
                 "steps": [{"from": a, "to": b, "type": EDGE_NAMES[c]}
                           for a, b, c in cycle]})
-    return out
 
 
 def check_list_append_device(hist, device: bool = True) -> dict:
     """Drop-in device-path analog of elle.check_list_append. Raises
     Unvectorizable when the history can't be interned."""
+    return analyze_list_append(hist, device)[0]
+
+
+def analyze_list_append(hist, device: bool = True):
+    """(result, DeviceAppendAnalysis): check_list_append_device's
+    verdict and the analysis behind it, whose dependency graph orders a
+    valid verdict's certificate. Raises Unvectorizable when the history
+    can't be interned."""
     if not isinstance(hist, History):
         hist = History(hist)
     prof = profiler.get()
     rec = prof.begin("elle-append")
-    with telemetry.span("elle:list-append") as sp:
-        with prof.phase(rec, "encode_ns"):
-            # host side: flatten + edge inference (the SCC launches
-            # inside cycle_anomalies_arrays profile themselves)
-            a = DeviceAppendAnalysis(hist, device=device)
-        if sp is not None:
-            sp["attrs"] = {"txns": a.flat.n,
-                           "edges": int(len(a.edge_src))}
+    with prof.phase(rec, "encode_ns"):
+        # host side: flatten + edge inference (the SCC launches
+        # inside cycle_anomalies_arrays profile themselves)
+        a = DeviceAppendAnalysis(hist, device=device)
     telemetry.count("elle.txns", a.flat.n)
     telemetry.count("elle.edges", int(len(a.edge_src)))
     rec.update(txns=a.flat.n, edges=int(len(a.edge_src)))
@@ -672,7 +759,7 @@ def check_list_append_device(hist, device: bool = True) -> dict:
         "anomalies": {k: v[:8] for k, v in anomalies.items()},
         "edge-count": int(len(a.edge_src)),
         "txn-count": a.flat.n,
-    }
+    }, a
 
 
 # ---------------------------------------------------------------------------
@@ -836,7 +923,6 @@ class DeviceRwAnalysis:
 
     def __init__(self, hist: History, device: bool = True):
         self.device = device
-        self._ops = list(hist)
         self.txns: list[Txn] | None = None
         f = self.flat = self._flatten(hist)
         self.anomalies: dict[str, list] = defaultdict(list)

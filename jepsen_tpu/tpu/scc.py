@@ -79,7 +79,8 @@ def _scc_program(n_pad: int, sweep_cap: int, round_cap: int,
     import jax.numpy as jnp
 
     def fixpoint(carry_c, src, dst, live_e, neutral):
-        """Scatter-max propagation to fixpoint; returns (c, converged)."""
+        """Scatter-max propagation to fixpoint; returns (c, converged,
+        sweeps run)."""
 
         def cond(state):
             c, changed, it = state
@@ -98,52 +99,68 @@ def _scc_program(n_pad: int, sweep_cap: int, round_cap: int,
             # loops stay in lockstep
             return nc, jnp.any(nc != c), it + 1
 
-        c, changed, _ = jax.lax.while_loop(
+        c, changed, it = jax.lax.while_loop(
             cond, body, (carry_c, jnp.bool_(True), jnp.int32(0)))
-        return c, jnp.logical_not(changed)
+        return c, jnp.logical_not(changed), it
 
     def one_round(active, src, dst, edge_on):
         """One coloring round. Returns (labels for nodes retired this
-        round, new active mask, converged)."""
+        round, new active mask, converged, sweeps run)."""
         node_ids = jnp.arange(n_pad, dtype=jnp.int32)
         live_e = jnp.logical_and(
             edge_on, jnp.logical_and(active[src], active[dst]))
         # 1. forward colors
         c0 = jnp.where(active, node_ids, jnp.int32(-1))
-        c, ok_f = fixpoint(c0, src, dst, live_e, jnp.int32(-1))
+        c, ok_f, it_f = fixpoint(c0, src, dst, live_e, jnp.int32(-1))
         # 2. backward membership within color classes, all roots at
         # once: m[v]=1 iff v reaches its color root inside the class.
         same_color = jnp.logical_and(live_e, c[src] == c[dst])
         m0 = jnp.where(jnp.logical_and(active, c == node_ids),
                        jnp.int32(1), jnp.int32(0))
         # propagate backward: m[u] |= m[v] for edge u->v in-class
-        m, ok_b = fixpoint(m0, dst, src, same_color, jnp.int32(0))
+        m, ok_b, it_b = fixpoint(m0, dst, src, same_color, jnp.int32(0))
         member = jnp.logical_and(active, m > 0)
         labels = jnp.where(member, c, jnp.int32(-1))
         return labels, jnp.logical_and(active, ~member), \
-            jnp.logical_and(ok_f, ok_b)
+            jnp.logical_and(ok_f, ok_b), it_f + it_b
 
-    def full(active0, src, dst, edge_on):
+    # the program's name in a device trace is jit_scc_peel
+    def scc_peel(active0, src, dst, edge_on):
         def cond(state):
-            active, _out, ok, rounds = state
+            active, _out, ok, rounds, _sweeps = state
             return ok & jnp.any(active) & (rounds < round_cap)
 
         def body(state):
-            active, out, ok, rounds = state
-            labels, new_active, converged = one_round(active, src, dst,
-                                                      edge_on)
+            active, out, ok, rounds, sweeps = state
+            labels, new_active, converged, it = one_round(
+                active, src, dst, edge_on)
             return (new_active, jnp.where(labels >= 0, labels, out),
-                    ok & converged, rounds + 1)
+                    ok & converged, rounds + 1, sweeps + it)
 
         out0 = jnp.full((n_pad,), -1, dtype=jnp.int32)
-        active, out, ok, _ = jax.lax.while_loop(
-            cond, body, (active0, out0, jnp.bool_(True), jnp.int32(0)))
+        active, out, ok, rounds, sweeps = jax.lax.while_loop(
+            cond, body, (active0, out0, jnp.bool_(True), jnp.int32(0),
+                         jnp.int32(0)))
         done = ok & jnp.logical_not(jnp.any(active))
-        # ok flag rides IN the labels array (slot n_pad-1 is sentinel
-        # territory): one device->host transfer instead of two.
-        return out.at[-1].set(done.astype(jnp.int32))
+        # the ok flag and the work counts ride IN the labels array (slot
+        # n_pad-1 is sentinel territory): one device->host transfer.
+        return out.at[-1].set(pack_status(done, rounds, sweeps))
 
-    return full
+    return scc_peel
+
+
+# The status word in the labels' sentinel slot: bit 0 the convergence
+# flag, bits 1-7 the peeling rounds (<= ROUND_CAP), bits 8 and up the
+# fixpoint sweeps, forward plus backward, summed over the rounds (<= 2 *
+# SWEEP_CAP * ROUND_CAP).
+def pack_status(done, rounds, sweeps):
+    return done.astype(rounds.dtype) | (rounds << 1) | (sweeps << 8)
+
+
+def unpack_status(word: int) -> tuple[bool, int, int]:
+    """(converged, rounds, sweeps) from the sentinel slot's word."""
+    word = int(word)
+    return bool(word & 1), (word >> 1) & 0x7F, word >> 8
 
 
 # The edge arrays (src/dst/edge_on — the big per-launch payload) are
@@ -298,6 +315,7 @@ def scc_device(n: int, src, dst, emask=None,
         t0 = monotonic_ns()
         labels = np.asarray(dev)
         rec["compute_ns"] = monotonic_ns() - t0
+        done, rec["rounds"], rec["sweeps"] = unpack_status(labels[-1])
     except BaseException:
         if fresh:
             # failed first launch: release the claim so the retry's
@@ -306,7 +324,9 @@ def scc_device(n: int, src, dst, emask=None,
         raise
     finally:
         prof.finish(rec)
-    if not labels[-1]:  # convergence flag (see _jitted_scc)
+    telemetry.count("scc.sweeps", rec["sweeps"])
+    telemetry.count("scc.rounds", rec["rounds"])
+    if not done:
         return None
     return labels[:n]
 

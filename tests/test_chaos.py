@@ -673,8 +673,7 @@ class TestDegradationLadder:
         def boom(h):
             raise RuntimeError("RESOURCE_EXHAUSTED: device oom")
 
-        monkeypatch.setattr(elle_device, "check_list_append_device",
-                            boom)
+        monkeypatch.setattr(elle_device, "analyze_list_append", boom)
         telemetry.reset()
         got = elle.check_list_append(hist, {"engine": "device"})
         assert got["valid?"] == want["valid?"]
