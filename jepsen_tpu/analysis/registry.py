@@ -230,9 +230,8 @@ def _scc_trace(b: dict, full: bool = False,
     else:
         fn = scc._jitted_scc_sharded(_mesh1(), n_pad, e_pad,
                                      scc.SWEEP_CAP, scc.ROUND_CAP)
-    sds = jax.ShapeDtypeStruct
-    args = (sds((n_pad,), np.bool_), sds((e_pad,), np.int32),
-            sds((e_pad,), np.int32), sds((e_pad,), np.bool_))
+    args = tuple(jax.ShapeDtypeStruct(s, d)
+                 for s, d in scc.scc_arg_shapes(n_pad, e_pad))
     traced = fn.trace(*args)
     jaxpr, staged, hlo, cost = _staged(traced, full)
     f, ln = _provenance(scc.scc_device)
@@ -244,9 +243,9 @@ def _scc_trace(b: dict, full: bool = False,
         partition=None if single else
         spmd.describe_partition(spmd.SCC_RULES, scc.SCC_ARGS),
         batch_axes=[] if single else
-        [("src", 0,
-          "edge list: scatter-max sweeps are per-edge "
-          "data-parallel")],
+        [("fwd_send", 0,
+          "edge list: each shard pulls each node's max over its "
+          "runs in its own block, per-edge data-parallel")],
         # edge buckets step linearly in 128Ki chunks above 2^17
         # (scc._edge_pad) — R5 prices that policy
         bucket_policy="linear", file=f, line=ln)

@@ -115,6 +115,11 @@ def jlog() -> ctypes.CDLL | None:
                 ctypes.c_int64, ctypes.POINTER(ctypes.c_int64),
                 ctypes.POINTER(ctypes.c_int64), ctypes.c_int64,
                 ctypes.POINTER(ctypes.c_int64)]
+            lib.jt_edge_layout.restype = ctypes.c_int64
+            i32 = ctypes.POINTER(ctypes.c_int32)
+            lib.jt_edge_layout.argtypes = [
+                ctypes.c_int64, ctypes.c_int64, ctypes.c_int64, i32, i32,
+                ctypes.POINTER(ctypes.c_uint8), i32, i32, i32, i32]
             _lib = lib
         except Exception:  # noqa: BLE001 — never break the store
             logger.exception("loading native jlog codec failed")
@@ -284,6 +289,32 @@ def realtime_edges(inv, comp):
         if m < 0:
             raise RuntimeError(f"native order sweep failed ({m})")
         return src[:m].copy(), dst[:m].copy()
+
+
+def edge_layout(recv, send, on, n_pad: int, blocks: int):
+    """One pass's SCC edge layout (order.c's jt_edge_layout): (recv,
+    send, off, end) as int32 arrays, the edges of each block counting-
+    sorted by receiver. Raises RuntimeError if the codec is unavailable
+    or a live receiver is out of range."""
+    import numpy as np
+
+    lib = jlog()
+    if lib is None or not hasattr(lib, "jt_edge_layout"):
+        raise RuntimeError("native edge layout unavailable")
+    recv = np.ascontiguousarray(recv, dtype=np.int32)
+    send = np.ascontiguousarray(send, dtype=np.int32)
+    on = np.ascontiguousarray(on, dtype=np.uint8)
+    outs = [np.empty(len(recv), dtype=np.int32) for _ in range(3)]
+    outs.append(np.empty(blocks * n_pad, dtype=np.int32))
+    p = ctypes.POINTER(ctypes.c_int32)
+    rc = lib.jt_edge_layout(
+        n_pad, blocks, len(recv), recv.ctypes.data_as(p),
+        send.ctypes.data_as(p), on.ctypes.data_as(
+            ctypes.POINTER(ctypes.c_uint8)),
+        *(o.ctypes.data_as(p) for o in outs))
+    if rc < 0:
+        raise RuntimeError(f"native edge layout failed ({rc})")
+    return tuple(outs)
 
 
 def topo_order(n: int, src, dst):

@@ -1,5 +1,6 @@
-/* Realtime-order edge sweep for the elle engines, and the topological
- * order of a verdict's dependency graph (jt_topo_order, below).
+/* Realtime-order edge sweep for the elle engines, the topological
+ * order of a verdict's dependency graph (jt_topo_order, below), and
+ * the SCC kernel's per-launch edge layout (jt_edge_layout, below).
  *
  * The reference's realtime relation comes from elle's
  * process/realtime graphs (jepsen/src/jepsen/tests/cycle/append.clj
@@ -145,6 +146,70 @@ int64_t jt_topo_order(int64_t n, const int64_t *src, const int64_t *dst,
     free(indeg);
     free(adj);
     return tail;
+}
+
+/* One pass's edge layout for the SCC kernel (tpu/scc.py _layout, whose
+ * numpy path is the reference): in each of `blocks` equal blocks of the
+ * m edges, a stable counting sort of the block's live edges (on[e] != 0)
+ * by receiving node, so each node's edges form one run; the block's
+ * masked-off edges follow as self-loops on the sentinel node n_pad-1,
+ * each with offset 0. Writes per edge the receiver, sender and offset in
+ * its run, and per block and node the run's last position in the block
+ * or -1. Returns 0; -1 if a live receiver lies outside [0, n_pad-1); -2
+ * on allocation failure. */
+int64_t jt_edge_layout(int64_t n_pad, int64_t blocks, int64_t m,
+                       const int32_t *recv, const int32_t *send,
+                       const uint8_t *on, int32_t *out_recv,
+                       int32_t *out_send, int32_t *out_off,
+                       int32_t *out_end) {
+    int64_t step = m / blocks;
+    /* int32 positions: the edge arrays are int32-indexed on the device */
+    int32_t *fill = (int32_t *)malloc(sizeof(int32_t) * (size_t)n_pad);
+    if (!fill)
+        return -2;
+    for (int64_t b = 0; b < blocks; b++) {
+        const int32_t *r = recv + b * step, *s = send + b * step;
+        const uint8_t *o = on + b * step;
+        int32_t *orecv = out_recv + b * step, *osend = out_send + b * step;
+        int32_t *ooff = out_off + b * step, *oend = out_end + b * n_pad;
+        for (int64_t v = 0; v < n_pad; v++)
+            fill[v] = 0;
+        for (int64_t e = 0; e < step; e++) {
+            if (!o[e])
+                continue;
+            if (r[e] < 0 || r[e] >= n_pad - 1) {
+                free(fill);
+                return -1;
+            }
+            fill[r[e]]++;
+        }
+        int32_t pos = 0;
+        for (int64_t v = 0; v < n_pad; v++) {
+            int32_t c = fill[v];
+            fill[v] = pos;
+            oend[v] = c ? pos + c - 1 : -1;
+            pos += c;
+        }
+        int32_t live = pos;
+        for (int64_t e = 0; e < step; e++) {
+            int32_t p;
+            if (o[e]) {
+                p = fill[r[e]]++;
+                orecv[p] = r[e];
+                osend[p] = s[e];
+            } else {
+                p = pos++;
+                orecv[p] = osend[p] = (int32_t)(n_pad - 1);
+            }
+        }
+        /* offsets in one sequential pass: a run starts where the
+         * receiver changes; masked-off edges each start their own */
+        for (int64_t p = 0; p < step; p++)
+            ooff[p] = (p > 0 && p < live && orecv[p] == orecv[p - 1])
+                      ? ooff[p - 1] + 1 : 0;
+    }
+    free(fill);
+    return 0;
 }
 
 #ifdef __cplusplus

@@ -12,14 +12,27 @@ ops that XLA maps well:
 
   repeat until no active nodes:
     1. forward pass — propagate the max node id ("color") along active
-       edges to a fixpoint: c[v] = max(c[v], max_{u->v} c[u]). Each
-       sweep is one scatter-max over the edge list; the fixpoint runs
-       in a lax.while_loop on device.
+       edges to a fixpoint: c[v] = max(c[v], max_{u->v} c[u]); the
+       fixpoint runs in a lax.while_loop on device.
     2. backward pass — for every color root r (c[r]==r), mark the
-       nodes that reach r inside r's color class; again a scatter-max
+       nodes that reach r inside r's color class; again a max
        fixpoint, all roots in parallel. Marked nodes = the exact SCC
        of each root (they reach r and r reaches them).
     3. retire every marked SCC; survivors recolor next round.
+
+Each sweep is a pull, not a scatter. Once per launch the host lays the
+edge list out twice (`_layout`, a counting sort in native/order.c): by
+dst for the forward pass, by src for the backward one, so each node's
+edges in that direction form one run. A sweep then reads each edge's
+sender color (a gather), takes a segmented max along the runs by
+log-doubling, and reads each node's maximum at its run's end. Every
+edge reads and none writes to a shared slot, as a scatter-max with
+colliding indices does; the sort stays on the host, out of the
+program's compile. The doubling levels are ceil(log2)
+of the longest run, read from the launch's own input (levels past it
+are skipped), and come back in the status word as `pull_levels`. Both
+passes are the same Jacobi sweeps as a scatter-max over the previous
+colors, so labels, sweeps and rounds do not depend on the formulation.
 
 Node ids follow history order, so dependency edges point mostly
 forward (u < v) and a forward sweep changes nothing for them: the
@@ -39,6 +52,7 @@ from __future__ import annotations
 import logging
 from functools import lru_cache
 from time import monotonic_ns
+from typing import Any, NamedTuple
 
 import numpy as np
 
@@ -68,19 +82,59 @@ def _scc_program(n_pad: int, sweep_cap: int, round_cap: int,
                  combine=None):
     """The ENTIRE Orzan peeling loop as one traceable program: rounds
     and fixpoints run in nested lax.while_loops, one download of
-    (labels, ok). A host-driven loop would sync device->host once per
-    sweep to test convergence; here the loop never leaves the device.
+    (labels, status). A host-driven loop would sync device->host once
+    per sweep to test convergence; here the loop never leaves the
+    device.
+
+    The edges arrive laid out by `_layout`: sorted by dst for the
+    forward pass and by src for the backward one, masked-off edges
+    turned into sentinel self-loops (node n_pad-1, never active).
 
     `combine` is the SPMD hook: the sharded path passes a pmax over
-    the mesh axis, turning each sweep's scatter-max into local
-    scatter over the shard's edge block + one small all-reduce of the
-    color array — the edge list (the big operand) never replicates."""
+    the mesh axis. Each shard pulls over its own edge block, and one
+    small all-reduce of the color array a sweep combines the shards'
+    partial maxima — the edge list (the big operand) never
+    replicates."""
     import jax
     import jax.numpy as jnp
+    from jax import lax
 
-    def fixpoint(carry_c, src, dst, live_e, neutral):
-        """Scatter-max propagation to fixpoint; returns (c, converged,
-        sweeps run)."""
+    def node_ids():
+        # built inside the trace: a closed-over array would be baked
+        # into the executable as a constant
+        return jnp.arange(n_pad, dtype=jnp.int32)
+
+    def runs(send, off, end):
+        # levels = ceil(log2(longest run)): shifts 1, 2, ...,
+        # 2^(levels-1) reach back over a whole run
+        longest = jnp.max(off) + 1
+        levels = sum(((1 << d) < longest).astype(jnp.int32)
+                     for d in range(_max_levels(off.shape[0])))
+        return _Runs(send, off, end, levels)
+
+    def pull(v, runs, neutral):
+        """One sweep's incoming maximum per node from each edge's
+        sender value `v`: a segmented max by log-doubling along the
+        sorted runs, read at each run's end. No scatter: every edge
+        reads, none writes to a shared slot."""
+        for d in range(_max_levels(v.shape[0])):
+            s = 1 << d
+
+            def step(v, s=s):
+                back = jnp.concatenate(
+                    [jnp.full((s,), neutral, dtype=v.dtype), v[:-s]])
+                return jnp.where(runs.off >= s, jnp.maximum(v, back), v)
+
+            # levels past the launch's longest run are skipped
+            v = lax.cond(d < runs.levels, step, lambda v: v, v)
+        return jnp.where(runs.end >= 0, v[jnp.maximum(runs.end, 0)],
+                         neutral)
+
+    def fixpoint(carry_c, active, runs, in_class, neutral):
+        """Pull propagation to fixpoint; returns (c, converged, sweeps
+        run). An inactive node holds the neutral and keeps it, so a
+        sender needs no mask, and masking the update at the node equals
+        masking each edge by both ends' activity."""
 
         def cond(state):
             c, changed, it = state
@@ -88,12 +142,13 @@ def _scc_program(n_pad: int, sweep_cap: int, round_cap: int,
 
         def body(state):
             c, _, it = state
-            vals = jnp.where(live_e, c[src], neutral)
-            prop = jnp.full((n_pad,), neutral, dtype=jnp.int32
-                            ).at[dst].max(vals)
+            v = c[runs.send]
+            if in_class is not None:
+                v = jnp.where(in_class, v, neutral)
+            prop = pull(v, runs, neutral)
             if combine is not None:
                 prop = combine(prop)
-            nc = jnp.maximum(c, prop)
+            nc = jnp.where(active, jnp.maximum(c, prop), c)
             # c and (combined) prop are replicated on the sharded
             # path, so `changed` agrees across shards and the while
             # loops stay in lockstep
@@ -103,29 +158,36 @@ def _scc_program(n_pad: int, sweep_cap: int, round_cap: int,
             cond, body, (carry_c, jnp.bool_(True), jnp.int32(0)))
         return c, jnp.logical_not(changed), it
 
-    def one_round(active, src, dst, edge_on):
+    def one_round(active, fwd, bwd, bwd_recv):
         """One coloring round. Returns (labels for nodes retired this
         round, new active mask, converged, sweeps run)."""
-        node_ids = jnp.arange(n_pad, dtype=jnp.int32)
-        live_e = jnp.logical_and(
-            edge_on, jnp.logical_and(active[src], active[dst]))
-        # 1. forward colors
-        c0 = jnp.where(active, node_ids, jnp.int32(-1))
-        c, ok_f, it_f = fixpoint(c0, src, dst, live_e, jnp.int32(-1))
+        # 1. forward colors, pulled along edges sorted by dst
+        ids = node_ids()
+        c0 = jnp.where(active, ids, jnp.int32(-1))
+        c, ok_f, it_f = fixpoint(c0, active, fwd, None, jnp.int32(-1))
         # 2. backward membership within color classes, all roots at
         # once: m[v]=1 iff v reaches its color root inside the class.
-        same_color = jnp.logical_and(live_e, c[src] == c[dst])
-        m0 = jnp.where(jnp.logical_and(active, c == node_ids),
+        # Pulled along edges sorted by src: m[u] |= m[v] for edge u->v
+        # in-class. An edge with an inactive end is in-class only if
+        # both ends are inactive (colors -1), and then carries m = 0.
+        in_class = c[bwd_recv] == c[bwd.send]
+        m0 = jnp.where(jnp.logical_and(active, c == ids),
                        jnp.int32(1), jnp.int32(0))
-        # propagate backward: m[u] |= m[v] for edge u->v in-class
-        m, ok_b, it_b = fixpoint(m0, dst, src, same_color, jnp.int32(0))
+        m, ok_b, it_b = fixpoint(m0, active, bwd, in_class, jnp.int32(0))
         member = jnp.logical_and(active, m > 0)
         labels = jnp.where(member, c, jnp.int32(-1))
         return labels, jnp.logical_and(active, ~member), \
             jnp.logical_and(ok_f, ok_b), it_f + it_b
 
     # the program's name in a device trace is jit_scc_peel
-    def scc_peel(active0, src, dst, edge_on):
+    def scc_peel(active0, fwd_send, fwd_off, fwd_end, bwd_send, bwd_recv,
+                 bwd_off, bwd_end):
+        fwd = runs(fwd_send, fwd_off, fwd_end)
+        bwd = runs(bwd_send, bwd_off, bwd_end)
+        levels = jnp.maximum(fwd.levels, bwd.levels)
+        if combine is not None:
+            levels = combine(levels)
+
         def cond(state):
             active, _out, ok, rounds, _sweeps = state
             return ok & jnp.any(active) & (rounds < round_cap)
@@ -133,7 +195,7 @@ def _scc_program(n_pad: int, sweep_cap: int, round_cap: int,
         def body(state):
             active, out, ok, rounds, sweeps = state
             labels, new_active, converged, it = one_round(
-                active, src, dst, edge_on)
+                active, fwd, bwd, bwd_recv)
             return (new_active, jnp.where(labels >= 0, labels, out),
                     ok & converged, rounds + 1, sweeps + it)
 
@@ -144,30 +206,99 @@ def _scc_program(n_pad: int, sweep_cap: int, round_cap: int,
         done = ok & jnp.logical_not(jnp.any(active))
         # the ok flag and the work counts ride IN the labels array (slot
         # n_pad-1 is sentinel territory): one device->host transfer.
-        return out.at[-1].set(pack_status(done, rounds, sweeps))
+        status = pack_status(done, rounds, sweeps, levels)
+        return lax.dynamic_update_slice(out, status[None], (n_pad - 1,))
 
     return scc_peel
 
 
+class _Runs(NamedTuple):
+    """One pass's edges on device, sorted by receiving node: per edge
+    its sender and offset in its node's run; per node its run's last
+    position or -1; the doubling levels the longest run needs."""
+
+    send: Any
+    off: Any
+    end: Any
+    levels: Any
+
+
+def _max_levels(e: int) -> int:
+    """Doubling levels that span any run of an e-edge block."""
+    return max(1, (e - 1).bit_length())
+
+
+def _layout(recv, send, on, n_pad: int, blocks: int = 1):
+    """One pass's edge layout, built on the host once per launch: in
+    each of `blocks` equal blocks (a shard's edges) the edges sorted by
+    receiving node, so each node's edges form one run. Masked-off edges
+    become self-loops on the sentinel node n_pad-1 (never active), each
+    its own run of one. Returns (recv, send, off, end): per edge its
+    receiver, sender and offset in its run; per block and node the
+    run's last position in the block, or -1. native/order.c's counting
+    sort does this in linear time; `_layout_py`'s stable sort is its
+    reference and fallback."""
+    from .. import native
+
+    try:
+        return native.edge_layout(recv, send, on, n_pad, blocks)
+    except RuntimeError:
+        return _layout_py(recv, send, on, n_pad, blocks)
+
+
+def _layout_py(recv, send, on, n_pad: int, blocks: int = 1):
+    sentinel = np.int32(n_pad - 1)
+    recv = np.where(on, recv, sentinel).reshape(blocks, -1)
+    send = np.where(on, send, sentinel).reshape(blocks, -1)
+    order = np.argsort(recv, axis=1, kind="stable")
+    recv = np.take_along_axis(recv, order, 1)
+    send = np.take_along_axis(send, order, 1)
+    live = recv != sentinel
+    base = np.arange(blocks, dtype=np.int32)[:, None] * n_pad
+    count = np.bincount((recv + base)[live], minlength=blocks * n_pad
+                        ).reshape(blocks, n_pad)
+    last = (np.cumsum(count, axis=1) - 1).astype(np.int32)
+    end = np.where(count > 0, last, -1)
+    first = last - count + 1
+    off = np.arange(recv.shape[1], dtype=np.int32) - \
+        np.take_along_axis(first, recv, 1)
+    off[~live] = 0
+    return recv.ravel(), send.ravel(), off.ravel(), end.ravel()
+
+
 # The status word in the labels' sentinel slot: bit 0 the convergence
-# flag, bits 1-7 the peeling rounds (<= ROUND_CAP), bits 8 and up the
+# flag, bits 1-7 the peeling rounds (<= ROUND_CAP), bits 8-24 the
 # fixpoint sweeps, forward plus backward, summed over the rounds (<= 2 *
-# SWEEP_CAP * ROUND_CAP).
-def pack_status(done, rounds, sweeps):
-    return done.astype(rounds.dtype) | (rounds << 1) | (sweeps << 8)
+# SWEEP_CAP * ROUND_CAP = 65,536), bits 25-29 the pull's doubling levels
+# (<= 31, the larger of the forward and backward layouts').
+def pack_status(done, rounds, sweeps, levels):
+    return (done.astype(rounds.dtype) | (rounds << 1) | (sweeps << 8)
+            | (levels << 25))
 
 
-def unpack_status(word: int) -> tuple[bool, int, int]:
-    """(converged, rounds, sweeps) from the sentinel slot's word."""
+def unpack_status(word: int) -> tuple[bool, int, int, int]:
+    """(converged, rounds, sweeps, pull levels) from the sentinel
+    slot's word."""
     word = int(word)
-    return bool(word & 1), (word >> 1) & 0x7F, word >> 8
+    return (bool(word & 1), (word >> 1) & 0x7F, (word >> 8) & 0x1FFFF,
+            (word >> 25) & 0x1F)
 
 
-# The edge arrays (src/dst/edge_on — the big per-launch payload) are
+# The two edge layouts (`_layout`: the big per-launch payload) are
 # donated: scc_device builds fresh device arrays per call, so XLA may
 # reuse them as scratch (graftlint R3). `active` stays live (tiny).
-DONATE_ARGNUMS = (1, 2, 3)
-SCC_ARGS = ("active", "src", "dst", "edge_on")
+SCC_ARGS = ("active", "fwd_send", "fwd_off", "fwd_end", "bwd_send",
+            "bwd_recv", "bwd_off", "bwd_end")
+DONATE_ARGNUMS = tuple(range(1, len(SCC_ARGS)))
+
+
+def scc_arg_shapes(n_pad: int, e_pad: int, blocks: int = 1):
+    """(shape, dtype) of each of SCC_ARGS for a launch of `blocks`
+    edge blocks (the mesh size on the sharded path): edge arrays hold
+    e_pad slots, run ends n_pad a block."""
+    edge, ends = ((e_pad,), np.int32), ((blocks * n_pad,), np.int32)
+    return (((n_pad,), np.bool_), edge, edge, ends, edge, edge, edge,
+            ends)
 
 
 @lru_cache(maxsize=None)
@@ -191,10 +322,10 @@ def _jitted_scc_sharded(mesh, n_pad: int, e_pad: int, sweep_cap: int,
                         round_cap: int):
     """SPMD compile: the edge list shards over the mesh's 'b' axis
     (in key blocks — see scc_device), the color array stays
-    replicated, and each sweep's fixpoint combines per-shard
-    scatter-max results with ONE pmax of n_pad ints. Per-sweep
-    compute and H2D both scale ~1/N in edges; the collective moves
-    node-count bytes, not edge-count."""
+    replicated, each shard sorts and pulls over its own block, and
+    each sweep combines the shards' partial maxima with ONE pmax of
+    n_pad ints. Per-sweep compute and H2D both scale ~1/N in edges;
+    the collective moves node-count bytes, not edge-count."""
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -278,6 +409,12 @@ def scc_device(n: int, src, dst, emask=None,
     pmask[:len(src)] = True if emask is None else np.asarray(emask)
     active = np.zeros(n_pad, dtype=bool)
     active[:n] = True
+    t0 = monotonic_ns()
+    blocks = n_dev if shard else 1
+    _, fsend, foff, fend = _layout(pdst, psrc, pmask, n_pad, blocks)
+    brecv, bsend, boff, bend = _layout(psrc, pdst, pmask, n_pad, blocks)
+    host = (active, fsend, foff, fend, bsend, brecv, boff, bend)
+    layout_ns = monotonic_ns() - t0
     prof = profiler.get()
     if shard:
         mesh = spmd.mesh_for(n_dev)
@@ -290,6 +427,7 @@ def scc_device(n: int, src, dst, emask=None,
         bucket = ("scc", n_pad, e_pad)
     rec = prof.begin("scc", bucket=bucket, nodes=n, edges=len(src),
                      devices=n_dev if shard else None)
+    rec["encode_ns"] = layout_ns
     fresh = prof.bucket_fresh("scc", bucket)
     t0 = monotonic_ns()
     if shard:
@@ -299,10 +437,9 @@ def scc_device(n: int, src, dst, emask=None,
         specs = spmd.match_partition_rules(spmd.SCC_RULES, SCC_ARGS)
         args = tuple(
             jax.device_put(a, NamedSharding(mesh, s))
-            for a, s in zip((active, psrc, pdst, pmask), specs))
+            for a, s in zip(host, specs))
     else:
-        args = (jnp.asarray(active), jnp.asarray(psrc),
-                jnp.asarray(pdst), jnp.asarray(pmask))
+        args = tuple(jnp.asarray(a) for a in host)
     rec["h2d_ns"] = monotonic_ns() - t0
     try:
         t0 = monotonic_ns()
@@ -315,7 +452,8 @@ def scc_device(n: int, src, dst, emask=None,
         t0 = monotonic_ns()
         labels = np.asarray(dev)
         rec["compute_ns"] = monotonic_ns() - t0
-        done, rec["rounds"], rec["sweeps"] = unpack_status(labels[-1])
+        (done, rec["rounds"], rec["sweeps"],
+         rec["pull_levels"]) = unpack_status(labels[-1])
     except BaseException:
         if fresh:
             # failed first launch: release the claim so the retry's
@@ -326,6 +464,7 @@ def scc_device(n: int, src, dst, emask=None,
         prof.finish(rec)
     telemetry.count("scc.sweeps", rec["sweeps"])
     telemetry.count("scc.rounds", rec["rounds"])
+    telemetry.count("scc.pull-levels", rec["pull_levels"])
     if not done:
         return None
     return labels[:n]

@@ -95,11 +95,12 @@ WGL_RULES = (
     (r"^inv_perm$", ()),
 )
 
-# SCC coloring kernel: the edge list (the big operand — the color
-# array is n_pad ints) shards over the mesh; colors stay replicated
-# and are pmax-combined per sweep.
+# SCC coloring kernel: the edge layouts (the big operand — the color
+# array is n_pad ints) shard over the mesh, one block of edges and its
+# run ends a device; colors stay replicated and are pmax-combined per
+# sweep.
 SCC_RULES = (
-    (r"^(src|dst|edge_on)$", (AXIS,)),
+    (r"^(fwd|bwd)_", (AXIS,)),
     (r"^active$", ()),
 )
 

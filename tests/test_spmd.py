@@ -98,7 +98,8 @@ class TestPlumbing:
         specs = spmd.match_partition_rules(spmd.SCC_RULES,
                                            scc.SCC_ARGS)
         assert specs[scc.SCC_ARGS.index("active")] == P()
-        assert specs[scc.SCC_ARGS.index("src")] == P(spmd.AXIS)
+        assert specs[scc.SCC_ARGS.index("fwd_send")] == P(spmd.AXIS)
+        assert specs[scc.SCC_ARGS.index("bwd_end")] == P(spmd.AXIS)
 
     def test_unmatched_arg_raises(self):
         with pytest.raises(ValueError, match="no partition rule"):
@@ -446,6 +447,32 @@ class TestSccSharded:
         _cap(monkeypatch, 8)
         got = scc.scc(n, src, dst, emask=emask)
         assert got.tolist() == ref.tolist()
+
+    def test_sharded_pull_matches_single(self, monkeypatch, devices8):
+        """Each shard sorts and pulls over its own edge block: labels,
+        sweeps and rounds equal the single-device launch's, and the
+        pull levels are those of the longest run inside one block."""
+        n, src, dst = self._graph(8)
+        emask = np.random.default_rng(3).random(len(src)) < 0.8
+        work = {}
+        for cap in (0, 8):
+            _cap(monkeypatch, cap)
+            telemetry.reset()
+            labels = scc.scc_device(n, src, dst, emask)
+            c = telemetry.get().counters()
+            work[cap] = (labels.tolist(), c["scc.sweeps"],
+                         c["scc.rounds"], c["scc.pull-levels"])
+        assert work[8][:3] == work[0][:3]
+        assert work[0][0] == scc._scc_host(n, src[emask],
+                                           dst[emask]).tolist()
+        # shard i holds edges [i * step, (i + 1) * step) of the padded
+        # array; the padding is masked off
+        step = scc._edge_pad(len(src)) // 8
+        longest = max(np.bincount(ends[i:i + step][emask[i:i + step]]).max()
+                      for ends in (src, dst)
+                      for i in range(0, len(src), step))
+        assert work[8][3] == (int(longest) - 1).bit_length()
+        assert work[8][3] <= work[0][3]
 
 
 # ---------------------------------------------------------------------------

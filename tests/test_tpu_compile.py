@@ -111,14 +111,14 @@ def test_wgl_kernel_compiles_for_v5e(one_chip, name):
     _fits(compiled)
 
 
-def _scc_args(n_pad, e_pad, shardings):
+def _scc_args(n_pad, e_pad, shardings, blocks=1):
     import jax
-    import jax.numpy as jnp
 
-    dtypes = (jnp.bool_, jnp.int32, jnp.int32, jnp.bool_)
-    shapes = ((n_pad,), (e_pad,), (e_pad,), (e_pad,))
+    from jepsen_tpu.tpu import scc
+
+    shapes = scc.scc_arg_shapes(n_pad, e_pad, blocks)
     return [jax.ShapeDtypeStruct(s, d, sharding=sh)
-            for s, d, sh in zip(shapes, dtypes, shardings)]
+            for (s, d), sh in zip(shapes, shardings)]
 
 
 SCC_CONFIG3 = (131072, 655360)  # (n_pad, e_pad) of 100k txns
@@ -129,7 +129,8 @@ def test_scc_compiles_for_v5e(one_chip):
 
     n_pad, e_pad = SCC_CONFIG3
     fn = scc._jitted_scc(n_pad, e_pad, scc.SWEEP_CAP, scc.ROUND_CAP)
-    _fits(fn.lower(*_scc_args(n_pad, e_pad, [one_chip] * 4)).compile())
+    _fits(fn.lower(*_scc_args(n_pad, e_pad, [one_chip] * len(scc.SCC_ARGS))
+                   ).compile())
 
 
 def test_scc_sharded_compiles_for_v5e_2x2(mesh4):
@@ -142,7 +143,8 @@ def test_scc_sharded_compiles_for_v5e_2x2(mesh4):
     fn = scc._jitted_scc_sharded(mesh4, n_pad, e_pad, scc.SWEEP_CAP,
                                  scc.ROUND_CAP)
     compiled = fn.lower(*_scc_args(
-        n_pad, e_pad, [NamedSharding(mesh4, s) for s in specs])).compile()
+        n_pad, e_pad, [NamedSharding(mesh4, s) for s in specs],
+        blocks=4)).compile()
     _fits(compiled)
     # the edge list stays sharded: the only collective is the per-sweep
     # pmax of the color array
