@@ -11,9 +11,9 @@ this package holds the drivers:
                   aggregates, renders, and gates against the
                   committed lint-baseline.json
 
-Surfaced via `python -m jepsen_tpu lint`, the web /lint page, bench's
-lint-wall line, and `lint.*` telemetry counters. doc/static-analysis.md
-is the rule catalog.
+Surfaced via `python -m jepsen_tpu lint`, the web /lint page, and
+`lint.*` telemetry counters. doc/static-analysis.md is the rule
+catalog.
 """
 
 from .driver import LintReport, run_lint  # noqa: F401

@@ -1,11 +1,11 @@
 """graftlint driver: registry x rules + concurrency lint + ratchet.
 
 `run_lint()` is the one entry point every surface shares — the
-`python -m jepsen_tpu lint` CLI, the web /lint page, bench's
-lint-wall line and tier-1's baseline gate. It traces every registry
-entry at its shape buckets (abstract tracing only: no execution, no
-devices, CPU-safe), runs R1-R6 over each trace, AST-audits the host
-feeder modules (R2) and the threaded harness modules (C1-C3), and
+`python -m jepsen_tpu lint` CLI, the web /lint page and tier-1's
+baseline gate. It traces every registry entry at its shape buckets
+(abstract tracing only: no execution, no devices, CPU-safe), runs
+R1-R6 over each trace, AST-audits the host feeder modules (R2) and
+the threaded harness modules (C1-C3), and
 reports through the house observability fabric (`lint.*` telemetry
 counters/gauges).
 """
@@ -151,7 +151,7 @@ def run_lint(runtime_buckets: bool = False,
     additionally traces the shapes this process actually compiled
     (profiler.shape_buckets()) and runs R5's bucket-cardinality
     cross-check. full=False stops at jax tracing (~0.1s/kernel, the
-    tier-1/bench mode); full=True also lowers each kernel for R4's
+    tier-1 mode); full=True also lowers each kernel for R4's
     HLO collective scan and XLA cost analysis (seconds)."""
     t0 = time.monotonic()
     rep = LintReport()
@@ -273,7 +273,7 @@ def main(argv=None) -> int:
         # the committed baseline's contract is the deterministic
         # default mode: pinning runtime-bucket or lowered-HLO
         # findings leaves entries every default-mode gate (tier-1,
-        # web, bench) reports as permanently stale
+        # web) reports as permanently stale
         print("--update pins the deterministic default mode only; "
               "drop --runtime-buckets/--full")
         return 254

@@ -253,7 +253,7 @@ def _scc_trace(b: dict, full: bool = False,
 
 # ---------------------------------------------------------------------------
 # Default shape buckets (deterministic; mirror the profiler's real
-# buckets from the bench configs: ensemble batches, segmented long
+# buckets from the BASELINE configs: ensemble batches, segmented long
 # histories, elle SCC graphs)
 # ---------------------------------------------------------------------------
 
@@ -272,7 +272,7 @@ WGL_SEG_BUCKETS = [
      "W": 24, "F": 48, "reach": True, "crash_free": False},
 ]
 SHARDED_BUCKETS = [
-    # the 1024-history ensemble bench (BASELINE config 5)
+    # the 1024-history ensemble (BASELINE config 5)
     {"label": "B1024xM512xS8", "K": 1025, "M": 512, "S": 8,
      "rows": 1024, "W": 32, "F": 64, "reach": False},
 ]
@@ -285,7 +285,7 @@ SLICES_BUCKETS = [
      "W": 24, "F": 48, "reach": True, "crash_free": False},
 ]
 SCC_BUCKETS = [
-    # elle dependency graphs at the 100k-txn bench scale
+    # elle dependency graphs at the 100k-txn scale (BASELINE config 3)
     {"label": "N131072xE262144", "n_pad": 131072, "e_pad": 262144},
 ]
 

@@ -321,7 +321,7 @@ class Linearizable(Checker):
         self.algorithm = opts.get("algorithm", "tpu")
         # checker-driven verdicts carry a machine-checkable proof by
         # default (jepsen_tpu.tpu.certify); raw wgl.analysis calls
-        # (bench kernels) don't pay for extraction
+        # don't pay for extraction
         self.certify = bool(opts.get("certify", True))
 
     @staticmethod

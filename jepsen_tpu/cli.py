@@ -299,9 +299,9 @@ def telemetry_cmd() -> dict:
 
 def profile_cmd() -> dict:
     """A 'profile' subcommand: the per-kernel device-performance table
-    for a stored run — launches, compile-cache hit rate, FLOPs, bytes
-    accessed, peak device memory, and the wall/device phase split —
-    from the run's metrics.json + telemetry.jsonl launch records
+    for a stored run — launches, compile-cache hit rate, compile and
+    wall time, and the wall/device phase split — from the run's
+    metrics.json + telemetry.jsonl launch records
     (jepsen_tpu.tpu.profiler; doc/observability.md)."""
     def build(p):
         return _store_run_opts(p)

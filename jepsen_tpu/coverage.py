@@ -49,6 +49,8 @@ import time
 from pathlib import Path
 from typing import Any, Iterable
 
+from . import util
+
 logger = logging.getLogger(__name__)
 
 SCHEMA = 1
@@ -424,11 +426,11 @@ def build_record(test: dict, recorder: Recorder | None = None) -> dict:
 
 
 def validate_record(rec) -> int:
-    """Schema check for a coverage.json document (the
-    ledger.validate_entries analog, run in tier-1): required keys,
-    fault entries with non-negative counts and 2-element windows,
-    anomaly entries with known outcomes. Returns fault + anomaly entry
-    count; raises ValueError on the first violation."""
+    """Schema check for a coverage.json document (run in tier-1):
+    required keys, fault entries with non-negative counts and
+    2-element windows, anomaly entries with known outcomes. Returns
+    fault + anomaly entry count; raises ValueError on the first
+    violation."""
     if not isinstance(rec, dict):
         raise ValueError("coverage record must be a dict")
     for key in ("schema", "run", "ts", "workload", "faults",
@@ -576,13 +578,11 @@ def _append_if_new(path: Path, have: dict, entry: dict) -> bool:
     if latest is not None and latest.get("digest") == entry["digest"]:
         return False
     path.parent.mkdir(parents=True, exist_ok=True)
-    # one os.write on an O_APPEND fd (ledger.atomic_append_line):
+    # one os.write on an O_APPEND fd (util.atomic_append_line):
     # concurrent runs appending to the shared atlas can interleave
     # LINES but never bytes — newest-line-wins stays sound because no
     # reader can ever see a spliced line
-    from . import ledger as jledger
-
-    jledger.atomic_append_line(path, json.dumps(entry))
+    util.atomic_append_line(path, json.dumps(entry))
     have[entry["run"]] = entry
     return True
 

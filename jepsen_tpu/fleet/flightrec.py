@@ -38,9 +38,8 @@ that pipeline the way a serving stack records requests:
             each launch fired (full / timeout / drain / breaker).
 
 Everything is advisory: a disabled recorder (FleetServer(...,
-flightrec=False)) turns every hook into an early return, and bench.py
-prices the instrumented-vs-disabled delta as the flightrec-overhead
-BENCH line (<2% of the fleet-throughput budget).
+flightrec=False)) turns every hook into an early return, and verdict
+files are byte-identical either way.
 """
 
 from __future__ import annotations

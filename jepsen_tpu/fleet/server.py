@@ -201,7 +201,7 @@ class FleetServer:
         self.stream_checks = stream_checks
         # the flight recorder is shared with the scheduler (its
         # launch/decision records land in the same session); disabled
-        # it costs nothing (bench.py prices the delta)
+        # every hook is an early return
         self.flightrec = frec.FlightRecorder(enabled=bool(flightrec))
         if self.scheduler.flightrec is None and flightrec:
             self.scheduler.flightrec = self.flightrec

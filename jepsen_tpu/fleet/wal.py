@@ -53,7 +53,7 @@ from pathlib import Path
 # the repo's one short-write loop (a silently-torn record behind an
 # ack would break the durability promise; better no ack than a half-
 # journaled chunk)
-from ..ledger import write_all
+from ..util import write_all
 
 WAL_MAGIC = b"JTPUWAL1"
 _HDR = struct.Struct("<II")

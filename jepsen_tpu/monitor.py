@@ -19,8 +19,7 @@ a fixed cadence and appends one JSON point per tick to a
 The interpreter feeds the monitor from its main loop (on_dispatch /
 on_complete / on_stall); all hooks are a few dict updates under one
 uncontended lock, cheap enough that the interpreter throughput-floor
-test passes with the monitor enabled (bench.py records the overhead
-delta as a BENCH line).
+test passes with the monitor enabled.
 
 Because points are appended and flushed incrementally, a *different
 process* (web.py's `/live/` SSE endpoint) can tail the file and stream

@@ -3,7 +3,7 @@
 Consumes the `profiler.<kernel>.*` counters/gauges the device profiler
 (jepsen_tpu.tpu.profiler) aggregates into a run's metrics.json, plus
 the per-launch `kernel:<name>` spans in telemetry.jsonl, and renders
-the per-kernel cost/occupancy table behind `python -m jepsen_tpu
+the per-kernel launch/occupancy table behind `python -m jepsen_tpu
 profile <run-dir>` and web.py's kernel-profile section. Pure functions
 over loaded artifacts — no recorder access."""
 
@@ -40,17 +40,6 @@ def _fmt_count(v) -> str:
     return f"{v:.0f}"
 
 
-def _fmt_bytes(v) -> str:
-    if v is None:
-        return "-"
-    v = float(v)
-    for unit, scale in (("GB", 1 << 30), ("MB", 1 << 20),
-                        ("kB", 1 << 10)):
-        if v >= scale:
-            return f"{v / scale:.1f}{unit}"
-    return f"{v:.0f}B"
-
-
 def _fmt_ms(ns) -> str:
     if not ns:
         return "-"
@@ -60,8 +49,8 @@ def _fmt_ms(ns) -> str:
 
 
 def kernel_rows(metrics: dict | None) -> list[dict]:
-    """One display row per kernel: formatted cost totals, cache hit
-    rate, and the wall-time split across pipeline phases (encode /
+    """One display row per kernel: launches, cache hit rate, compile
+    and wall time, and the wall-time split across pipeline phases (encode /
     H2D / dispatch / compute / D2H, as % of the summed phase time —
     dispatch includes compile on a bucket's first launch, which the
     separate compile column calls out)."""
@@ -82,9 +71,6 @@ def kernel_rows(metrics: dict | None) -> list[dict]:
             "kernel": kernel,
             "launches": int(st.get("launches", 0)),
             "cache": (f"{hits}/{looked}" if looked else "-"),
-            "flops": _fmt_count(st.get("flops")),
-            "bytes": _fmt_bytes(st.get("bytes")),
-            "peak_mem": _fmt_bytes(st.get("peak_memory_bytes")),
             "compile": _fmt_ms(st.get("compile_ns")),
             "wall": _fmt_ms(st.get("wall_ns")),
             "split": split,
@@ -104,9 +90,7 @@ def kernel_rows(metrics: dict | None) -> list[dict]:
 
 
 _COLS = (("kernel", "kernel"), ("launches", "launches"),
-         ("cache", "cache hit"), ("flops", "FLOPs"),
-         ("bytes", "bytes"), ("peak_mem", "peak mem"),
-         ("compile", "compile"), ("wall", "wall"),
+         ("cache", "cache hit"), ("compile", "compile"), ("wall", "wall"),
          ("split", "wall split"), ("iterations", "iters"),
          ("frontier", "frontier"), ("states", "states"),
          ("dedup", "dedup"))

@@ -24,7 +24,7 @@ events, per the trace-event spec):
   nemesis  one track per nemesis spec, a slice per activation window
   device   one track per compiled kernel (wgl, scc, ...): each launch
            record (jepsen_tpu.tpu.profiler) is a slice carrying its
-           FLOPs/bytes/phase-split attrs
+           bucket/phase-split attrs
   node <n> one process per DB node (jepsen_tpu.nodeprobe): counter
            tracks (`C` events) for CPU/memory/network/clock-offset —
            the offset series merges the probe ticks with the
@@ -126,7 +126,7 @@ def _span_events(events: list, spans) -> int:
 def _device_events(events: list, spans) -> int:
     """Device-launch records (the profiler's `kernel:<name>` telemetry
     spans) as one track per kernel: each launch is a slice carrying
-    its cost/phase attrs (FLOPs, bytes, compile/compute split), so a
+    its bucket/phase attrs (rows, compile/compute split), so a
     kernel launch lines up against the checker phase and the ops it
     was checking on the shared clock."""
     launches = [s for s in spans

@@ -40,12 +40,13 @@ certificates replayed against an edited history all fail loudly
 can't be extracted say so honestly (`{"absent": reason}`) — an absent
 certificate is allowed, a validating-but-wrong one never is.
 
-Extraction cost is bounded (a node budget on the order search) and
-priced by bench.py's certificate-overhead line. A segmented verdict
-starts its certificate's digest and orders on the host while the
-device still runs its later waves (`Prefetch`); JEPSEN_TPU_CERTIFY=0
-disables extraction entirely (verdicts then carry an honest absent
-marker rather than nothing, so downstream walks stay uniform).
+Extraction cost is bounded (a node budget on the order search); the
+benchmark's `certify.attach` span reads what is left of it on a
+verdict's critical path. A segmented verdict starts its certificate's
+digest and orders on the host while the device still runs its later
+waves (`Prefetch`); JEPSEN_TPU_CERTIFY=0 disables extraction entirely
+(verdicts then carry an honest absent marker rather than nothing, so
+downstream walks stay uniform).
 """
 
 from __future__ import annotations
@@ -1254,7 +1255,7 @@ def _wgl_certificate(model, hist, enc, result, prefetch=None) -> dict:
 def attach_wgl(model, hist, enc, result,
                prefetch: Prefetch | None = None) -> dict:
     """Attaches a certificate to a wgl analysis result (checker entry
-    points call this; raw bench/kernel paths don't). Disabled
+    points call this; raw kernel paths don't). Disabled
     extraction still leaves an honest absent marker so result walks
     stay uniform. `prefetch`: the verdict's Prefetch (wgl.analysis),
     or None. Counts the segment orders of a certificate made
@@ -1550,7 +1551,7 @@ def _elle_certificate(hist, result, family: str, graph=None) -> dict:
 
 def attach_elle(hist, result, family: str, graph=None) -> dict:
     """Attaches a certificate to an elle check result (the checker
-    wrappers opt in via opts['certify']; raw bench calls don't).
+    wrappers opt in via opts['certify']; raw calls don't).
     `graph`: the device engine's list-append analysis, or None."""
     if not isinstance(result, dict):
         return result

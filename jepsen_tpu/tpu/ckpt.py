@@ -51,7 +51,7 @@ import threading
 import zlib
 from pathlib import Path
 
-from .. import telemetry
+from .. import telemetry, util
 
 CKPT_MAGIC = b"JTPUCKP1"
 _HDR = struct.Struct("<II")
@@ -244,9 +244,7 @@ def write(path, rec: dict) -> Path:
         tmp = p.with_suffix(".tmp")
         fd = os.open(tmp, os.O_CREAT | os.O_TRUNC | os.O_WRONLY)
         try:
-            from ..ledger import write_all
-
-            write_all(fd, data)
+            util.write_all(fd, data)
             os.fsync(fd)
         finally:
             os.close(fd)

@@ -62,9 +62,9 @@ def ensure_initialized() -> bool:
             jax.distributed.initialize(**kwargs)
         except RuntimeError as e:
             # initialize() must precede the first JAX computation;
-            # call ensure_initialized() at program entry (core.run,
-            # bench.main) — reaching here later degrades to
-            # single-host rather than crashing the check
+            # call ensure_initialized() at program entry (core.run)
+            # — reaching here later degrades to single-host rather
+            # than crashing the check
             logger.warning("jax.distributed.initialize failed "
                            "(call earlier in the program): %s", e)
             return False

@@ -576,7 +576,7 @@ def _with_search(result: dict, key_edges: dict | None = None) -> dict:
     """Attaches result['search'] — the search explorer's elle half:
     edge volume, witnessing-cycle count, and (host engine) the per-key
     edge cost attribution. Mirrored into elle.search.* telemetry so
-    the profile CLI and ledger see search-shape drift."""
+    the profile CLI sees search-shape drift."""
     s: dict = {"edges": int(result.get("edge-count") or 0),
                "txns": int(result.get("txn-count") or 0)}
     cycles = sum(1 for recs in (result.get("anomalies") or {}).values()
@@ -600,7 +600,7 @@ def _finish(result: dict, hist, family: str,
             graph=None) -> dict:
     """Shared tail of both public checks: search stats always, a
     verdict certificate when the caller opted in (checker wrappers
-    pass opts['certify']; raw bench calls don't pay for proofs).
+    pass opts['certify']; raw calls don't pay for proofs).
     `graph`: the device engine's list-append analysis, whose dependency
     graph orders a valid verdict's certificate."""
     _with_search(result, key_edges)

@@ -231,8 +231,7 @@ def sharded_launch(pb: PackedBatch, rows: Sequence[tuple[int, int]],
             "batch": pb.B, "m": pb.M, "states": pb.S,
             "devices": n_dev, "device_entries": lay.device_entries,
             "balance": balance}
-    return _timed_launch(bucket, lambda: fn(*args), kernel=kernel,
-                         lower=lambda: fn.lower(*args), meta=meta)
+    return _timed_launch(bucket, lambda: fn(*args), kernel=kernel, meta=meta)
 
 
 def check_batch_sharded(encs: Sequence[Encoded], mesh=None, W: int = 32,

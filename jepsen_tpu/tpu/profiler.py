@@ -6,11 +6,6 @@ site (wgl batched search, the mesh-sharded ensemble path, the SCC
 coloring kernel, the elle device engines, plus the host-side encode and
 pack stages that feed them) reports a per-launch record:
 
-  - lowered-HLO cost analysis: FLOPs, bytes accessed (from
-    jax.stages.Lowered.cost_analysis()) and peak device memory
-    (argument + output + temp sizes from Compiled.memory_analysis()),
-    computed ONCE per compile bucket and attached to every launch of
-    that bucket;
   - the wall vs device split: host encode / H2D transfer / dispatch /
     device compute / D2H readback, each phase timed separately;
   - compile-cache accounting per shape bucket (hooked into the
@@ -25,10 +20,10 @@ launch mirrors into telemetry as a `kernel:<name>` span (so it lands in
 telemetry.jsonl and the Perfetto export's device track) and as
 `profiler.<kernel>.*` counters/gauges (so metrics.json carries the
 aggregate the `profile` CLI and web section render). The recorder is
-always on and adds two dict updates per launch; cost analysis costs one
-lowering per NEW bucket only (JEPSEN_TPU_PROFILE_COST=0 disables it).
+always on and adds two dict updates per launch. A kernel's static
+FLOPs/bytes are graftlint's (analysis/registry.py); peak device memory
+is Device.memory_stats()'s.
 
-Cross-run trending lives in jepsen_tpu.ledger (the bench perf ledger);
 parallel_efficiency() below is the scaling metric that
 `chip_smoke.py --chips 4` reports for the sharded paths.
 """
@@ -50,34 +45,6 @@ logger = logging.getLogger(__name__)
 # that isn't one of these is still additive host time (compile_ns).
 PHASES = ("encode_ns", "h2d_ns", "dispatch_ns", "compute_ns", "d2h_ns")
 
-# Cost fields attached per bucket (None when the backend can't say).
-COST_FIELDS = ("flops", "bytes_accessed", "peak_memory_bytes")
-
-_COST_ENABLED = os.environ.get("JEPSEN_TPU_PROFILE_COST", "1") != "0"
-
-
-def _memory_analysis_enabled() -> bool:
-    """Whether peak-memory stats are worth their price. flops/bytes
-    come from Lowered.cost_analysis() (no compile), but
-    memory_analysis needs a Compiled — and Lowered.compile() does NOT
-    reuse the jit dispatch path's executable, so it's a second full
-    XLA compile per fresh bucket unless something makes it cheap:
-    the CPU backend (sub-second compiles) or a persistent compilation
-    cache serving it from disk (bench enables one; a ~35s TPU kernel
-    compile must not be paid twice). JEPSEN_TPU_PROFILE_MEMORY
-    overrides in either direction."""
-    env = os.environ.get("JEPSEN_TPU_PROFILE_MEMORY")
-    if env is not None:
-        return env != "0"
-    try:
-        import jax
-
-        if jax.default_backend() == "cpu":
-            return True
-        return bool(jax.config.jax_compilation_cache_dir)
-    except Exception:  # noqa: BLE001 — no jax, no memory stats
-        return False
-
 # Per-launch records are mirrored into telemetry individually only up
 # to this many launches per kernel per run; past it, only aggregates
 # accumulate (a 1024-history ensemble must not write 1024 span lines).
@@ -96,69 +63,24 @@ def max_mirrored_launches() -> int:
         return MAX_MIRRORED_LAUNCHES
 
 
-def _fresh_bucket_cost(lower: Callable, bucket_key) -> dict:
-    """FLOPs / bytes / peak memory for a newly-compiled bucket.
-
-    `lower` is a zero-arg thunk returning the jax.stages.Lowered for
-    the same (args, static) the launch used. flops/bytes read off the
-    Lowered alone (no compile); peak memory needs Lowered.compile(),
-    which is a second XLA compile of the bucket, so it only runs when
-    _memory_analysis_enabled() says that's cheap. Any failure (backend
-    without cost analysis, jax API drift) degrades to None fields —
-    profiling must never break a launch."""
-    cost: dict = {k: None for k in COST_FIELDS}
-    if not _COST_ENABLED or lower is None:
-        return cost
-    try:
-        lowered = lower()
-        ca = lowered.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
-        if isinstance(ca, dict):
-            if ca.get("flops") is not None:
-                cost["flops"] = float(ca["flops"])
-            if ca.get("bytes accessed") is not None:
-                cost["bytes_accessed"] = float(ca["bytes accessed"])
-        ma = None
-        if _memory_analysis_enabled():
-            try:
-                ma = lowered.compile().memory_analysis()
-            except Exception:  # noqa: BLE001 — memory is optional
-                ma = None
-        if ma is not None:
-            peak = sum(
-                int(getattr(ma, f, 0) or 0)
-                for f in ("argument_size_in_bytes",
-                          "output_size_in_bytes",
-                          "temp_size_in_bytes"))
-            if peak:
-                cost["peak_memory_bytes"] = peak
-    except Exception as e:  # noqa: BLE001 — profiling is best-effort
-        logger.debug("cost analysis failed for bucket %r: %r",
-                     bucket_key, e)
-    return cost
-
-
 class Profiler:
     """Per-launch device-profile recorder. Thread-safe; one global
     instance (get()) serves the process, tests may make their own.
     `enabled=False` makes it a no-op recorder: records still open and
     park (call sites mutate them unconditionally) but nothing is
-    aggregated, mirrored to telemetry, or cost-analyzed."""
+    aggregated or mirrored to telemetry."""
 
     # concurrency-lint contract (jepsen_tpu.analysis.concurrency,
     # doc/static-analysis.md): these attrs are written under _lock
     # only (or in *_locked methods whose callers hold it)
     _guarded_by_lock = {"_lock": ("_records", "_pending",
-                                  "_bucket_cost", "_seen_buckets",
-                                  "cache_stats")}
+                                  "_seen_buckets", "cache_stats")}
 
     def __init__(self, enabled: bool = True):
         self.enabled = enabled
         self._lock = threading.Lock()
         self._records: list[dict] = []
         self._pending: dict[int, dict] = {}   # id(device out) -> record
-        self._bucket_cost: dict[Any, dict] = {}
         self._seen_buckets: dict[str, set] = {}
         self.cache_stats: dict[str, dict] = {}
 
@@ -231,23 +153,6 @@ class Profiler:
         with self._lock:
             self._seen_buckets.get(site, set()).discard(bucket)
 
-    def bucket_cost(self, bucket, lower: Callable | None,
-                    fresh: bool) -> dict:
-        """The bucket's cost analysis: computed on first sight (when
-        `fresh`, right after its compile), served from cache after."""
-        if not self.enabled:
-            return {k: None for k in COST_FIELDS}
-        with self._lock:
-            cached = self._bucket_cost.get(bucket)
-        if cached is not None:
-            return cached
-        if not fresh and lower is None:
-            return {k: None for k in COST_FIELDS}
-        cost = _fresh_bucket_cost(lower, bucket)
-        with self._lock:
-            self._bucket_cost.setdefault(bucket, cost)
-        return cost
-
     def attach(self, out, rec: dict) -> Any:
         """Parks an open record until the launch's output is drained
         (the async-dispatch handoff: _launch returns, _drain blocks).
@@ -317,13 +222,6 @@ class Profiler:
         if rec.get("frontier_peak"):
             tel.gauge_max(f"profiler.{k}.frontier_peak",
                           int(rec["frontier_peak"]))
-        if rec.get("flops"):
-            tel.count(f"profiler.{k}.flops", int(rec["flops"]))
-        if rec.get("bytes_accessed"):
-            tel.count(f"profiler.{k}.bytes", int(rec["bytes_accessed"]))
-        if rec.get("peak_memory_bytes"):
-            tel.gauge_max(f"profiler.{k}.peak_memory_bytes",
-                          int(rec["peak_memory_bytes"]))
         if rec.get("devices"):
             tel.gauge_max(f"profiler.{k}.devices", int(rec["devices"]))
         if rec.get("balance") is not None:
@@ -364,7 +262,7 @@ class Profiler:
             return list(self._records)
 
     def reset(self) -> None:
-        """Clears per-run state. Bucket cost/seen caches persist: they
+        """Clears per-run state. The bucket seen-sets persist: they
         mirror the process-level XLA compile cache, which a new run
         still hits."""
         with self._lock:

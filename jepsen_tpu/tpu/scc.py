@@ -447,8 +447,6 @@ def scc_device(n: int, src, dst, emask=None,
         rec["dispatch_ns"] = monotonic_ns() - t0
         if fresh:
             rec["compile_ns"] = rec["dispatch_ns"]
-        rec.update(prof.bucket_cost(bucket, lambda: fn.lower(*args),
-                                    fresh))
         t0 = monotonic_ns()
         labels = np.asarray(dev)
         rec["compute_ns"] = monotonic_ns() - t0

@@ -156,8 +156,7 @@ def enable_compile_cache() -> str:
     and returns it: JAX_COMPILATION_CACHE_DIR when set (jax reads it at
     import, and this leaves it alone), else DEFAULT_CACHE_DIR. Called
     lazily by every kernel jit factory, before the first compile and
-    never at import. A warm cache makes first-check compile ~0 and lets
-    the profiler's memory_analysis compile come from disk."""
+    never at import. A warm cache makes first-check compile ~0."""
     import jax
 
     d = jax.config.jax_compilation_cache_dir

@@ -763,7 +763,7 @@ _compiled_buckets: set = set()
 _buckets_lock = _threading.Lock()
 
 
-def _timed_launch(bucket, dispatch, kernel: str = "wgl", lower=None,
+def _timed_launch(bucket, dispatch, kernel: str = "wgl",
                   meta: dict | None = None):
     """Runs a kernel-dispatch thunk with first-launch-per-bucket
     compile accounting. Shared by the single-device path below and the
@@ -775,10 +775,9 @@ def _timed_launch(bucket, dispatch, kernel: str = "wgl", lower=None,
     belongs.
 
     Profiling: opens a per-launch profiler record (kernel/bucket/meta,
-    dispatch + compile phases, per-bucket cost analysis via `lower` —
-    a zero-arg thunk returning the jax Lowered) and parks it against
-    the dispatched output; _drain closes it with the device-wait and
-    readback phases."""
+    dispatch + compile phases) and parks it against the dispatched
+    output; _drain closes it with the device-wait and readback
+    phases."""
     import jax
 
     with _buckets_lock:
@@ -812,9 +811,6 @@ def _timed_launch(bucket, dispatch, kernel: str = "wgl", lower=None,
     if fresh:
         jax.block_until_ready(out)
         rec["compile_ns"] = _time.monotonic_ns() - t0
-    # cost analysis: computed once right after the bucket's compile
-    # (the executable cache is warm), replayed from cache for hits
-    rec.update(prof.bucket_cost(bucket, lower, fresh))
     return prof.attach(out, rec)
 
 
@@ -856,8 +852,7 @@ def _launch(pb: PackedBatch, rows: Sequence[tuple[int, int]], W: int,
             "m": pb.M, "states": pb.S}
     return _timed_launch(
         bucket, lambda: _jitted_kernel()(*args, **kw),
-        kernel="wgl-reach" if reach else "wgl",
-        lower=lambda: _jitted_kernel().lower(*args, **kw), meta=meta)
+        kernel="wgl-reach" if reach else "wgl", meta=meta)
 
 
 def _downsample(xs, n: int = 32) -> list[int]:
@@ -1818,7 +1813,7 @@ def analysis(model, hist, algorithm: str = "tpu", W: int | None = None,
     (OOM / compile) and analysis stepped down the degradation ladder,
     the verdict carries the rungs walked as result['degradation'].
 
-    certify=True (the checker entry points pass it; raw bench paths
+    certify=True (the checker entry points pass it; raw kernel paths
     don't) additionally attaches a machine-checkable proof of the
     verdict as result['certificate'] (jepsen_tpu.tpu.certify) — for
     valid, a per-segment linearization order re-derived from the reach
@@ -1985,7 +1980,7 @@ def analysis_batch_streamed(model, hists: Sequence, chunk: int = 256,
     device search. A one-chunk drain lag keeps at most two chunks of
     packed tensors live on the host while preserving the overlap.
     certify=True attaches a per-result verdict certificate (the
-    checker batch path passes it; the raw bench path doesn't)."""
+    checker batch path passes it; a raw call doesn't)."""
     hists = list(hists)
     results: list[dict] = [None] * len(hists)  # type: ignore
 

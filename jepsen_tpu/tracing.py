@@ -39,8 +39,7 @@ shared crash-tolerance contract of telemetry.jsonl).
 The recorder is OFF by default: `test["trace?"] = True` opts a run in
 (core.run wires the lifecycle), and every record call begins with one
 `enabled` check, so a disabled tracer costs nothing on the
-interpreter's hot path (bench.py's trace-overhead line records the
-enabled cost).
+interpreter's hot path.
 """
 
 from __future__ import annotations
@@ -63,7 +62,7 @@ KINDS = ("op", "client", "remote", "event")
 
 # Stream-write cadence: a background writer thread drains completed
 # records every interval, serializing and writing OFF the interpreter
-# hot path (per-record dumps+write there cost the dummy-op bench ~3x;
+# hot path (per-record dumps+write there cost a dummy-op run ~3x;
 # the hot path pays one lock + two list appends per record). Rare
 # interesting kinds (remote, event) wake the writer immediately so
 # tailers see faults as they land.

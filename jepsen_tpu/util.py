@@ -9,12 +9,45 @@ nemesis-intervals util.clj:780).
 from __future__ import annotations
 
 import math
+import os
 import random
 import threading
 import time as _time
 from concurrent.futures import ThreadPoolExecutor
 from contextlib import contextmanager
 from typing import Any, Callable, Iterable, Sequence
+
+# ---------------------------------------------------------------------------
+# Durable writes
+# ---------------------------------------------------------------------------
+
+def write_all(fd: int, buf: bytes) -> None:
+    """os.write until every byte lands, raising on a zero-progress
+    write — the one short-write loop shared by every crash-safe
+    write in the repo (the coverage atlas, the fleet WAL, checkpoint
+    records). A silently-torn record behind a durability promise is
+    the failure mode this exists to kill."""
+    view = memoryview(buf)
+    while view:
+        n = os.write(fd, view)
+        if n <= 0:
+            raise OSError("short write")
+        view = view[n:]
+
+
+def atomic_append_line(path, line: str) -> None:
+    """One whole line, one os.write, O_APPEND: the shared-file append
+    primitive (the coverage atlas). Two writers can interleave LINES
+    but never bytes. Short writes (ENOSPC, signals) are continued
+    rather than silently torn — the continuation can interleave with
+    another writer only in the already-degraded disk-full case, where
+    the torn-tail read rule still drops the damage."""
+    fd = os.open(path, os.O_APPEND | os.O_CREAT | os.O_WRONLY, 0o644)
+    try:
+        write_all(fd, (line + "\n").encode())
+    finally:
+        os.close(fd)
+
 
 # ---------------------------------------------------------------------------
 # Relative time

@@ -429,18 +429,6 @@ class TestSearchExplorer:
         assert "witnessed at" in html             # the marker
         assert "certified" in html
 
-    def test_ledger_accepts_search_fields(self):
-        from jepsen_tpu import ledger
-
-        entry = {"round": 1, "ts": 1.0,
-                 "headline": {"value": 10.0}, "kernels": {},
-                 "search": {"witness_position": 0.85,
-                            "frontier_peak": 128}}
-        assert ledger.validate_entries([entry]) == 1
-        bad = dict(entry, search={"witness_position": "nope"})
-        with pytest.raises(ValueError):
-            ledger.validate_entries([bad])
-
 
 class TestCoverageWitnessPosition:
     def test_witness_frac_folds_into_atlas_cells(self):

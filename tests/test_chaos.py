@@ -656,6 +656,32 @@ class TestDegradationLadder:
         assert "degradation" in res[2]
         assert "degradation" in res[3]
 
+    def test_streamed_forced_host_keeps_every_verdict(self, monkeypatch):
+        """A dead device changes no verdict of a streamed ensemble with
+        crashed ops and an invalid member: every history falls back to
+        the host search and says so."""
+        from jepsen_tpu.checker import models
+        from jepsen_tpu.tpu import synth, wgl
+
+        m = models.cas_register()
+        hists = [synth.register_history(40, n_procs=4, seed=2000 + i,
+                                        crash_p=0.1) for i in range(6)]
+        hists[3], _bad = synth.corrupt_register_history(hists[3],
+                                                        at_frac=0.5)
+        want = wgl.analysis_batch_streamed(m, hists, chunk=2)
+        assert [r["valid?"] for r in want] == [True] * 3 + [False] \
+            + [True] * 2
+
+        def boom(*a, **kw):
+            raise RuntimeError("RESOURCE_EXHAUSTED: forced device "
+                               "failure")
+
+        monkeypatch.setattr(wgl, "_launch", boom)
+        got = wgl.analysis_batch_streamed(m, hists, chunk=2)
+        assert [r["valid?"] for r in got] == [r["valid?"] for r in want]
+        assert all(r["degradation"] == ["oom"]
+                   and "host-fallback" in r["analyzer"] for r in got)
+
     def test_elle_device_failure_falls_back_to_host(self, monkeypatch):
         from jepsen_tpu.tpu import elle, elle_device
 

@@ -7,7 +7,7 @@ import json
 import threading
 
 from jepsen_tpu import checker as chk
-from jepsen_tpu import control, core, independent, testing
+from jepsen_tpu import control, core, independent, net, testing
 from jepsen_tpu import generator as gen
 from jepsen_tpu.checker import models
 from jepsen_tpu.control.core import Action, Result
@@ -31,6 +31,9 @@ def getent_responder(node, action):
 
 
 def make_test(responder=getent_responder, nodes=("n1", "n2", "n3")):
+    # net.ip memoizes per process: an address another test's responder
+    # gave "n1" must not stand in for this responder's
+    net.clear_ip_cache()
     remote = DummyRemote(responder)
     t = testing.noop_test()
     t.update(nodes=list(nodes), remote=remote,

@@ -12,6 +12,7 @@ certificates IDENTICAL to solo runs, with admission control rejecting
 """
 
 import json
+import os
 import socket
 import threading
 import time
@@ -19,7 +20,7 @@ import time
 import numpy as np
 import pytest
 
-from jepsen_tpu import chaos, core, ledger, telemetry, testing
+from jepsen_tpu import chaos, core, telemetry, testing, util
 from jepsen_tpu import generator as gen
 from jepsen_tpu.checker import models
 from jepsen_tpu.fleet import client as fclient
@@ -1161,14 +1162,14 @@ class TestCli:
 
 class TestSharedLedgerAppends:
     def test_two_writer_ledger_stress(self, tmp_path):
-        path = tmp_path / "bench_ledger.jsonl"
+        path = tmp_path / "shared.jsonl"
         n_per = 200
         errs = []
 
         def writer(wid):
             try:
                 for i in range(n_per):
-                    ledger.atomic_append_line(
+                    util.atomic_append_line(
                         path, json.dumps({"w": wid, "i": i,
                                           "pad": "x" * 200}))
             except Exception as e:  # noqa: BLE001
@@ -1224,12 +1225,66 @@ class TestSharedLedgerAppends:
         assert len(entries) == 120  # nothing lost, nothing spliced
         assert len(coverage.dedup_entries(entries)) == 120
 
-    def test_ledger_append_entry_single_write(self, tmp_path):
+    def test_ledger_append_entry_single_write(self, tmp_path,
+                                              monkeypatch):
+        """One appended line is one os.write: the property that keeps
+        concurrent writers from splicing bytes."""
         p = tmp_path / "l.jsonl"
-        e = ledger.append_entry(p, {"round": 1, "headline":
-                                    {"value": 1.0}, "kernels": {}})
-        got = ledger.read_entries(p)
-        assert got == [e]
+        real, calls = os.write, []
+
+        def write(fd, buf):
+            if os.path.samestat(os.fstat(fd), os.stat(p)):
+                calls.append(bytes(buf))
+            return real(fd, buf)
+
+        monkeypatch.setattr(os, "write", write)
+        entry = {"round": 1, "headline": {"value": 1.0}}
+        util.atomic_append_line(p, json.dumps(entry))
+        util.atomic_append_line(p, json.dumps(entry))
+        assert calls == [(json.dumps(entry) + "\n").encode()] * 2
+        assert [json.loads(ln) for ln in p.read_text().splitlines()] \
+            == [entry, entry]
+
+
+class TestWriteAll:
+    """util.write_all: the short-write loop behind the WAL, checkpoint
+    records and the shared-file appends."""
+
+    def test_short_writes_continue_to_the_end(self, tmp_path,
+                                              monkeypatch):
+        real, sizes = os.write, []
+        path = tmp_path / "f"
+        fd = os.open(path, os.O_CREAT | os.O_WRONLY)
+
+        def one_byte(wfd, buf):
+            if wfd != fd:
+                return real(wfd, buf)
+            sizes.append(len(buf))
+            return real(wfd, bytes(buf[:1]))
+
+        monkeypatch.setattr(os, "write", one_byte)
+        try:
+            util.write_all(fd, b"abcdef")
+        finally:
+            monkeypatch.undo()
+            os.close(fd)
+        assert path.read_bytes() == b"abcdef"
+        # each call is handed exactly what is still unwritten
+        assert sizes == [6, 5, 4, 3, 2, 1]
+
+    def test_zero_progress_write_raises(self, tmp_path, monkeypatch):
+        path = tmp_path / "f"
+        fd = os.open(path, os.O_CREAT | os.O_WRONLY)
+        real, writes = os.write, iter([2, 0])
+        monkeypatch.setattr(
+            os, "write",
+            lambda wfd, buf: next(writes) if wfd == fd else real(wfd, buf))
+        try:
+            with pytest.raises(OSError, match="short write"):
+                util.write_all(fd, b"abcdef")
+        finally:
+            monkeypatch.undo()
+            os.close(fd)
 
 
 # ---------------------------------------------------------------------------

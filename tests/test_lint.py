@@ -511,11 +511,9 @@ class TestProductionSweep:
         assert r2 == ["_SegmentCheckpoint.__init__:int64"]
 
     def test_aggregates_shape(self, production_report):
-        """THE ISSUE-15 acceptance ledger block: the SPMD rebuild
-        drove R3 non-donated bytes, R4 replicated bytes and R4
-        unsharded batch axes all to zero — the per-round perf-ledger
-        `lint` block (bench_lint_wall feeds these exact aggregates)
-        records it from now on."""
+        """The SPMD rebuild drove R3 non-donated bytes, R4 replicated
+        bytes and R4 unsharded batch axes all to zero; the lint
+        report's aggregates hold them there."""
         agg = production_report.aggregates()
         assert agg["non_donated_bytes"] == 0
         assert agg["replicated_bytes"] == 0
@@ -573,7 +571,7 @@ class TestProductionSweep:
 
 
 # ---------------------------------------------------------------------------
-# Satellites: profiler shape buckets + runtime cardinality, web, ledger
+# Satellites: profiler shape buckets + runtime cardinality, web
 # ---------------------------------------------------------------------------
 
 class TestShapeBuckets:
@@ -624,16 +622,3 @@ def test_web_lint_page_and_panel(monkeypatch):
     assert "graftlint" in html and "R4" in html
     panel = web.lint_panel_html()  # now served from the cache
     assert "/lint" in panel and "unsharded axes" in panel
-
-
-def test_ledger_lint_field_validates():
-    from jepsen_tpu import ledger
-
-    entry = {"round": 1, "ts": 1.0, "kind": "bench",
-             "headline": {"value": 1.0}, "kernels": {},
-             "lint": {"non_donated_bytes": 100, "replicated_bytes": 0,
-                      "unsharded_axes": 4, "findings": {"R3": 3}}}
-    assert ledger.validate_entries([entry]) == 1
-    bad = dict(entry, lint={"non_donated_bytes": "lots"})
-    with pytest.raises(ValueError):
-        ledger.validate_entries([bad])

@@ -1,7 +1,6 @@
 """Device-performance observability: the kernel profiler (per-launch
-cost records, cache accounting, wall/device split), the cross-run perf
-ledger + slow-bleed detector, the profile CLI/table, the Prometheus
-endpoint, and the metrics/ledger schema validators (ISSUE 6)."""
+records, cache accounting, wall/device split), the profile CLI/table,
+the Prometheus endpoint, and the metrics schema validator."""
 
 from __future__ import annotations
 
@@ -12,7 +11,7 @@ import urllib.request
 import numpy as np
 import pytest
 
-from jepsen_tpu import ledger, telemetry, util
+from jepsen_tpu import telemetry, util
 from jepsen_tpu.checker import models
 from jepsen_tpu.reports import profile as rprofile
 from jepsen_tpu.reports import telemetry as rtel
@@ -40,16 +39,12 @@ def _launch_small(seed=1):
 
 
 class TestLaunchRecords:
-    def test_wgl_cost_fields_present_and_plausible(self, fresh):
+    def test_wgl_phase_fields_present_and_plausible(self, fresh):
         res = _launch_small()
         assert int(res[0]) == wgl.VALID
         recs = [r for r in fresh.records() if r["kernel"] == "wgl"]
         assert recs, "no wgl launch record"
         r = recs[0]
-        # cost analysis: a 64-entry search still moves real work
-        assert r["flops"] and r["flops"] > 1e3
-        assert r["bytes_accessed"] and r["bytes_accessed"] > 1e3
-        assert r["peak_memory_bytes"] and r["peak_memory_bytes"] > 1e3
         # wall/device split: the pipeline phases all recorded, and sum
         # within the record's wall time (monotonic vs linear clocks
         # differ, so allow slack via presence + positivity only)
@@ -79,10 +74,10 @@ class TestLaunchRecords:
         c = telemetry.get().counters()
         assert c["profiler.wgl.compile.hit"] == 1
         assert c["profiler.wgl.compile.miss"] == 2
-        # hit launches reuse the bucket's cached cost analysis
+        # only the hit launch skips the compile phase
         hits = [r for r in fresh.records()
                 if r["kernel"] == "wgl" and "compile_ns" not in r]
-        assert hits and all(r.get("flops") for r in hits)
+        assert len(hits) == 1
 
     def test_scc_launch_record(self, fresh):
         rng = np.random.default_rng(0)
@@ -94,7 +89,6 @@ class TestLaunchRecords:
         assert recs
         r = recs[0]
         assert r["nodes"] == n and r["edges"] == e
-        assert r["flops"] and r["bytes_accessed"]
         assert r.get("compute_ns", 0) > 0
 
     def test_elle_launch_record(self, fresh):
@@ -136,7 +130,7 @@ class TestLaunchRecords:
         spans = telemetry.get().events()
         kernel_spans = [s for s in spans
                         if s["name"].startswith("kernel:")]
-        assert kernel_spans and kernel_spans[0]["attrs"]["flops"]
+        assert kernel_spans and kernel_spans[0]["attrs"]["rows"]
         doc = rtrace.chrome_trace({}, [], spans)
         rtrace.validate_chrome_trace(doc)
         dev = [e for e in doc["traceEvents"]
@@ -149,6 +143,56 @@ class TestLaunchRecords:
                    if e.get("pid") == rtrace._PID_HARNESS
                    and str(e.get("name", "")).startswith("kernel:")]
         assert not harness
+
+    @pytest.mark.parametrize("site", ["wgl", "wgl-sharded", "scc"])
+    def test_fresh_bucket_launch_never_lowers_again(self, fresh,
+                                                    monkeypatch, site):
+        """A bucket's first launch compiles once, through the jit
+        dispatch; the profiler lowers nothing of its own."""
+        from jepsen_tpu.tpu import ensemble
+
+        lowered = []
+
+        class CountLower:
+            def __init__(self, fn):
+                self.fn = fn
+
+            def __call__(self, *a, **kw):
+                return self.fn(*a, **kw)
+
+            def lower(self, *a, **kw):
+                lowered.append(site)
+                return self.fn.lower(*a, **kw)
+
+        def counted(mod, name):
+            real = getattr(mod, name)
+            monkeypatch.setattr(
+                mod, name, lambda *a, **kw: CountLower(real(*a, **kw)))
+
+        monkeypatch.setenv("JEPSEN_TPU_SPMD", "0")
+        if site == "wgl":
+            counted(wgl, "_jitted_kernel")
+            assert int(_launch_small()[0]) == wgl.VALID
+        elif site == "wgl-sharded":
+            counted(ensemble, "_jitted_sharded")
+            encs = [encode(models.cas_register(),
+                           synth.register_history(24, n_procs=3, seed=i))
+                    for i in range(2)]
+            res = ensemble.check_batch_sharded(
+                encs, mesh=ensemble.default_mesh(1), W=16, F=16)
+            assert all(int(r) == wgl.VALID for r in res)
+        else:
+            counted(scc, "_jitted_scc")
+            rng = np.random.default_rng(0)
+            n, e = 2000, 25_000
+            fresh.bucket_unclaim("scc", ("scc", scc._next_pow2(n + 1),
+                                         scc._edge_pad(e)))
+            assert scc.scc_device(n, rng.integers(0, n, e),
+                                  rng.integers(0, n, e)) is not None
+        assert fresh.cache_stats[site]["misses"] == 1
+        recs = [r for r in fresh.records() if r["kernel"] == site]
+        assert recs and recs[0]["compile_ns"] > 0
+        assert lowered == []
 
     def test_metrics_json_schema_validates(self, fresh, tmp_path):
         _launch_small()
@@ -201,9 +245,6 @@ class TestRecorderGuards:
         p.record_host("pack", 100, entries=5)
         p.finish(rec)
         assert p.records() == [] and p.cache_stats == {}
-        # bucket_cost must not pay the lowering either
-        cost = p.bucket_cost(("b",), lambda: 1 / 0, True)
-        assert cost == {k: None for k in profiler.COST_FIELDS}
         assert not [c for c in telemetry.get().counters()
                     if c.startswith("profiler.")]
 
@@ -269,12 +310,6 @@ class TestRecorderGuards:
         c = telemetry.get().counters()
         assert c["profiler.wgl.launches"] == 257
 
-    def test_memory_analysis_env_off(self, fresh, monkeypatch):
-        monkeypatch.setenv("JEPSEN_TPU_PROFILE_MEMORY", "0")
-        assert not profiler._memory_analysis_enabled()
-        monkeypatch.setenv("JEPSEN_TPU_PROFILE_MEMORY", "1")
-        assert profiler._memory_analysis_enabled()
-
 
 class TestProfileReport:
     def _metrics(self, fresh):
@@ -289,11 +324,10 @@ class TestProfileReport:
         w = by_kernel["wgl"]
         assert w["launches"] == 1
         assert w["cache"] == "0/1"
-        assert w["flops"] != "-" and w["bytes"] != "-"
-        assert w["peak_mem"] != "-"
+        assert w["compile"] != "-" and w["wall"] != "-"
         assert "compute" in w["split"]
         text = rprofile.profile_text(telemetry.get().events(), m)
-        assert "FLOPs" in text and "wgl" in text
+        assert "cache hit" in text and "wgl" in text
         assert "Slowest launches" in text
         html = rprofile.profile_html(m)
         assert "kernel profile" in html and "wgl" in html
@@ -309,7 +343,7 @@ class TestProfileReport:
         rc = cmd["run"](p.parse_args([str(tmp_path)]))
         out = capsys.readouterr().out
         assert rc == 0
-        assert "FLOPs" in out and "peak mem" in out and "wgl" in out
+        assert "cache hit" in out and "wall split" in out and "wgl" in out
 
     def test_empty_profile(self):
         assert "no kernel launches" in rprofile.profile_text([], {})
@@ -360,181 +394,6 @@ class TestPrometheus:
             assert ei.value.code == 404
         finally:
             server.shutdown()
-
-
-class TestLedger:
-    def test_slow_bleed_fires_on_drift(self):
-        # three consecutive 10% drops — each under the 20% per-round
-        # gate — accumulate into a flagged bleed
-        v = ledger.slow_bleed([100.0, 100.0, 90.0, 81.0, 72.9])
-        assert v["bleeding"] is True
-        assert v["drop"] > 0.15
-
-    def test_slow_bleed_silent_on_noise(self):
-        v = ledger.slow_bleed([100.0, 96.0, 104.0, 99.0, 101.0])
-        assert v["bleeding"] is False
-        # a one-round dip that recovers is noise, not a bleed
-        assert ledger.slow_bleed([100.0, 85.0, 100.0])["bleeding"] \
-            is False
-
-    def test_slow_bleed_needs_history(self):
-        assert ledger.slow_bleed([100.0, 50.0])["bleeding"] is False
-
-    def test_slow_bleed_lower_is_better(self):
-        # seconds creeping UP is a bleed when lower is better
-        v = ledger.slow_bleed([10.0, 10.0, 11.1, 12.3, 13.7],
-                              higher_is_better=False)
-        assert v["bleeding"] is True
-        v = ledger.slow_bleed([13.7, 12.3, 11.1, 10.0, 10.0],
-                              higher_is_better=False)
-        assert v["bleeding"] is False
-
-    def _entry(self, rnd, hl=70000.0, **kernels):
-        return {"round": rnd, "ts": 1000.0 + rnd,
-                "headline": {"metric": "m", "value": hl,
-                             "unit": "ops/s"},
-                "kernels": {k: ({"value": v, "higher_is_better": True}
-                                if isinstance(v, (int, float)) else v)
-                            for k, v in kernels.items()}}
-
-    def test_detect_attributes_per_kernel(self):
-        entries = [self._entry(i + 1,
-                               wgl=100.0 * (0.9 ** max(0, i - 1)),
-                               elle=50.0 + (i % 2))
-                   for i in range(5)]
-        verdicts = ledger.detect(entries)
-        assert verdicts["wgl"]["bleeding"] is True
-        assert verdicts["elle"]["bleeding"] is False
-
-    def test_append_read_validate_roundtrip(self, tmp_path):
-        path = tmp_path / ledger.LEDGER_FILE
-        for i in range(3):
-            ledger.append_entry(path, self._entry(i + 1, wgl=100.0))
-        entries = ledger.read_entries(path)
-        assert ledger.validate_entries(entries) == 3
-        assert ledger.next_round(entries) == 4
-        # torn trailing line is dropped, not raised
-        with open(path, "a") as f:
-            f.write('{"round": 4, "ts"')
-        assert len(ledger.read_entries(path)) == 3
-
-    def test_validate_rejects_bad_entries(self):
-        good = self._entry(1)
-        with pytest.raises(ValueError, match="monotonic"):
-            ledger.validate_entries([good, self._entry(1)])
-        with pytest.raises(ValueError, match="missing"):
-            ledger.validate_entries([{"round": 1, "ts": 1.0}])
-        bad = self._entry(2)
-        bad["headline"] = {"metric": "m"}
-        with pytest.raises(ValueError, match="headline"):
-            ledger.validate_entries([bad])
-
-
-class TestRegressionGate:
-    """The bench gate now compares against the BEST of the last 3
-    rounds: two consecutive ~15% drops can't slip through."""
-
-    def _gate(self, monkeypatch, tmp_path, rounds):
-        import bench
-
-        path = tmp_path / ledger.LEDGER_FILE
-        for i, v in enumerate(rounds):
-            ledger.append_entry(path, {
-                "round": i + 1, "ts": float(i),
-                "headline": {"metric": "m", "value": v,
-                             "unit": "ops/s"},
-                "kernels": {}})
-        monkeypatch.setattr(bench, "_ledger_path", lambda: str(path))
-        return bench
-
-    def test_two_15pct_drops_trip_the_gate(self, monkeypatch,
-                                           tmp_path):
-        bench = self._gate(monkeypatch, tmp_path, [100_000.0, 85_000.0])
-        line = bench._check_regression(
-            {"metric": "m", "value": 72_250.0, "unit": "ops/s"})
-        # old gate: 72.25k vs 85k = -15%, passes. New gate: vs best of
-        # the window (100k) = -27.75%, trips.
-        assert line.get("regression") is True
-        assert line["prev_value"] == 100_000.0
-        assert line["prev_rounds"] == [1, 2]
-
-    def test_single_small_drop_passes(self, monkeypatch, tmp_path):
-        bench = self._gate(monkeypatch, tmp_path, [100_000.0])
-        line = bench._check_regression(
-            {"metric": "m", "value": 90_000.0, "unit": "ops/s"})
-        assert "regression" not in line
-        assert line["vs_prev"] == 0.9
-
-    def test_ledger_update_appends_and_flags_bleed(self, monkeypatch,
-                                                   tmp_path):
-        # synthetic drift fixture: three 10% drops already on the
-        # ledger; this round continues the drift. The per-round gate
-        # (20%) never tripped, the bleed detector must.
-        bench = self._gate(monkeypatch, tmp_path,
-                           [100_000.0, 100_000.0, 90_000.0, 81_000.0])
-        headline = {"metric": "m", "value": 72_900.0, "unit": "ops/s",
-                    "runs_s": [1.0], "spread": 0.1}
-        headline = bench._ledger_update([], headline)
-        entries = ledger.read_entries(tmp_path / ledger.LEDGER_FILE)
-        assert len(entries) == 5  # appended this round
-        assert entries[-1]["round"] == 5
-        assert headline["slow_bleed"]["headline"] > 0.15
-
-    def test_ledger_entry_shape(self, monkeypatch, tmp_path):
-        bench = self._gate(monkeypatch, tmp_path, [])
-        lines = [{"metric": "elle list-append cycle check (10k txns)",
-                  "value": 5000.0, "unit": "txns/s"},
-                 {"metric": "time-to-first-anomaly (x)", "value": 3.2,
-                  "unit": "s"}]
-        headline = {"metric": "m", "value": 70_000.0, "unit": "ops/s",
-                    "runs_s": [1.0], "spread": 0.1,
-                    "encode_s": 2.5, "check_s": 11.5}
-        entry = bench._ledger_entry(lines, headline)
-        assert entry["round"] == 1
-        assert entry["kernels"]["elle-append"]["value"] == 5000.0
-        assert entry["kernels"]["anomaly"]["higher_is_better"] is False
-        assert entry["kernels"]["encode"]["value"] == 2.5
-        assert entry["kernels"]["wgl-segmented"]["value"] == 11.5
-        ledger.validate_entries([entry | {"ts": 1.0}])
-
-    def test_kernel_lines_read_the_profiler_counters(self, monkeypatch,
-                                                     tmp_path, fresh):
-        """bench's compile share and search stats sum the
-        profiler.<kernel>.* counters over the wgl launch family."""
-        bench = self._gate(monkeypatch, tmp_path, [])
-        telemetry.count("profiler.wgl.compile_ns", 3_000_000)
-        telemetry.count("profiler.wgl-reach.compute_ns", 5_000_000)
-        telemetry.count("profiler.wgl-sharded.d2h_ns", 2_000_000)
-        telemetry.count("profiler.wgl.states", 40)
-        telemetry.count("profiler.wgl-reach.states", 2)
-        telemetry.count("profiler.wgl-reach.dedup_hits", 7)
-        telemetry.gauge_max("profiler.wgl-reach.frontier_peak", 9)
-        share = next(ln for ln in bench._telemetry_lines()
-                     if "compile share" in ln["metric"])
-        assert share["value"] == 0.3
-        headline = {"metric": "m", "value": 1.0, "unit": "ops/s"}
-        search = bench._ledger_entry([], headline)["search"]
-        assert search == {"states_explored": 42, "dedup_hits": 7,
-                          "frontier_peak": 9}
-
-
-def test_bench_exits_nonzero_after_printing_when_a_config_raises(
-        monkeypatch, capsys):
-    import bench
-
-    def boom(_n):
-        raise RuntimeError("config failed")
-
-    monkeypatch.setenv("BENCH_SKIP_EXTRAS", "1")
-    monkeypatch.setattr(bench, "bench_headline", boom)
-    monkeypatch.setattr(bench, "_telemetry_lines",
-                        lambda: [{"metric": "telemetry", "value": 1}])
-    with pytest.raises(SystemExit) as exc:
-        bench.main()
-    assert exc.value.code == 1
-    out = capsys.readouterr()
-    assert '"metric": "telemetry"' in out.out
-    assert "bench_headline failed" in out.err
 
 
 class TestScalingAttribution:

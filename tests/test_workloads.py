@@ -242,6 +242,20 @@ class TestSynthGenerators:
         h = synth.bank_history(800, seed=5)
         assert bank.check_fast(h, 80)["valid?"] is True
 
+    def test_bank_history_read_count_matches_fold(self):
+        """check_fast counts exactly the ok reads that a plain per-op
+        fold sees, and each of them conserves the total."""
+        from jepsen_tpu.tpu import synth
+        from jepsen_tpu.workloads import bank
+        h = synth.bank_history(2000, seed=11)
+        res = bank.check_fast(h, 80)
+        assert res["valid?"] is True
+        reads = [op.value for op in h if op.type == "ok"
+                 and op.f == "read" and op.value is not None]
+        assert res["read-count"] == len(reads) > 0
+        assert all(sum(r.values()) == 80 and min(r.values()) >= 0
+                   for r in reads)
+
     def test_register_history_with_crashes_valid(self):
         from jepsen_tpu.checker import models
         from jepsen_tpu.tpu import synth, wgl
