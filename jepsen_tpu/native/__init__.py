@@ -120,6 +120,9 @@ def jlog() -> ctypes.CDLL | None:
             lib.jt_edge_layout.argtypes = [
                 ctypes.c_int64, ctypes.c_int64, ctypes.c_int64, i32, i32,
                 ctypes.POINTER(ctypes.c_uint8), i32, i32, i32, i32]
+            lib.jt_off_spine.restype = ctypes.c_int64
+            lib.jt_off_spine.argtypes = [ctypes.c_int64] + [
+                ctypes.POINTER(ctypes.c_int64)] * 6
             _lib = lib
         except Exception:  # noqa: BLE001 — never break the store
             logger.exception("loading native jlog codec failed")
@@ -315,6 +318,24 @@ def edge_layout(recv, send, on, n_pad: int, blocks: int):
     if rc < 0:
         raise RuntimeError(f"native edge layout failed ({rc})")
     return tuple(outs)
+
+
+def off_spine(rd_key, rd_len, sp_off, sp_vals, re_vals):
+    """The read elements that differ from their key's spine at their
+    position (order.c's jt_off_spine), as ascending int64 indices into
+    re_vals. Raises RuntimeError if the codec is unavailable."""
+    import numpy as np
+
+    lib = jlog()
+    if lib is None or not hasattr(lib, "jt_off_spine"):
+        raise RuntimeError("native prefix pass unavailable")
+    args = [np.ascontiguousarray(x, dtype=np.int64)
+            for x in (rd_key, rd_len, sp_off, sp_vals, re_vals)]
+    out = np.empty(len(args[-1]), dtype=np.int64)
+    p = ctypes.POINTER(ctypes.c_int64)
+    m = lib.jt_off_spine(len(args[0]), *(x.ctypes.data_as(p) for x in args),
+                         out.ctypes.data_as(p))
+    return out[:m].copy()
 
 
 def topo_order(n: int, src, dst):

@@ -1,6 +1,7 @@
 /* Realtime-order edge sweep for the elle engines, the topological
- * order of a verdict's dependency graph (jt_topo_order, below), and
- * the SCC kernel's per-launch edge layout (jt_edge_layout, below).
+ * order of a verdict's dependency graph (jt_topo_order, below), the
+ * SCC kernel's per-launch edge layout (jt_edge_layout, below), and the
+ * list-append prefix pass (jt_off_spine, below).
  *
  * The reference's realtime relation comes from elle's
  * process/realtime graphs (jepsen/src/jepsen/tests/cycle/append.clj
@@ -146,6 +147,27 @@ int64_t jt_topo_order(int64_t n, const int64_t *src, const int64_t *dst,
     free(indeg);
     free(adj);
     return tail;
+}
+
+/* The prefix pass of list-append edge inference (tpu/elle_device.py
+ * _off_spine_py is the reference): element j of read r, whose rd_len[r]
+ * elements follow those of reads 0..r-1 in re_vals, against element j
+ * of its key's spine, sp_vals[sp_off[rd_key[r]] + j]. The spine is the
+ * key's longest read, so that element exists. Writes the indices of
+ * the elements that differ, ascending, into out (room for every
+ * element) and returns their count. */
+int64_t jt_off_spine(int64_t R, const int64_t *rd_key,
+                     const int64_t *rd_len, const int64_t *sp_off,
+                     const int64_t *sp_vals, const int64_t *re_vals,
+                     int64_t *out) {
+    int64_t m = 0, e = 0;
+    for (int64_t r = 0; r < R; r++) {
+        const int64_t *sp = sp_vals + sp_off[rd_key[r]];
+        for (int64_t j = 0, n = rd_len[r]; j < n; j++, e++)
+            if (re_vals[e] != sp[j])
+                out[m++] = e;
+    }
+    return m;
 }
 
 /* One pass's edge layout for the SCC kernel (tpu/scc.py _layout, whose

@@ -9,9 +9,13 @@ correctness reference; this module re-derives the same anomalies with:
 
   1. one flattening pass turning txn micro-ops into dense int arrays
      (txn ids, interned keys, (key, value) pair ids);
-  2. numpy segment ops for writer resolution, version orders (spines),
-     read anomalies (G1a/G1b/internal/unobservable/incompatible), and
-     ww/wr/rw edge inference — no per-element Python;
+  2. numpy segment ops for version orders (spines), writer
+     resolution, read anomalies (G1a/G1b/internal/unobservable/
+     incompatible), and ww/wr/rw edge inference — no per-element
+     Python. Read elements resolve by spine position: one elementwise
+     pass checks that each read is a prefix of its key's spine, and an
+     element on its spine takes the spine element's pair id, so only
+     appends, spines and the elements off them are interned;
   3. cycle detection through the batched label-propagation SCC kernel
      (jepsen_tpu.tpu.scc) on device, host scipy on fallback;
   4. host-side cycle witness extraction and classification (shared
@@ -29,7 +33,7 @@ from collections import defaultdict
 import numpy as np
 
 from .. import history as h
-from .. import telemetry
+from .. import native, telemetry
 from ..history import History
 from . import profiler
 from . import scc as scc_mod
@@ -219,6 +223,18 @@ def _pairs_by_key(a_key, b_key, b_val):
     return np.repeat(np.arange(len(a_key)), reps), bv[base + step]
 
 
+def _off_spine_py(rd_key, rd_len, sp_off, sp_vals, re_vals):
+    """native/order.c's jt_off_spine in numpy: the indices of the read
+    elements (each read's rd_len elements following the previous
+    read's in re_vals) that differ from the spine element at their
+    position, sp_vals[sp_off[key] + j]. The spine is its key's longest
+    read, so that element exists."""
+    starts = np.cumsum(rd_len) - rd_len
+    at = np.repeat(sp_off[rd_key] - starts, rd_len)
+    at += np.arange(len(re_vals))
+    return np.flatnonzero(sp_vals[at] != re_vals)
+
+
 class DeviceAppendAnalysis:
     """Mirrors elle.AppendAnalysis over Flat arrays. Flattening runs
     through the C pass (native.elle_flatten) when available; txn/op
@@ -233,8 +249,8 @@ class DeviceAppendAnalysis:
         self.flat = self._flatten(hist)
         self.anomalies: dict[str, list] = defaultdict(list)
         with telemetry.span("elle.edges"):
-            self._resolve_writers()
             self._spines()
+            self._resolve_writers()
             self._read_anomalies()
             data = self._data_edges()
         with telemetry.span("elle.order-edges"):
@@ -242,8 +258,6 @@ class DeviceAppendAnalysis:
              self.edge_key) = self._with_order_edges(*data)
 
     def _flatten(self, hist: History):
-        from .. import native
-
         with telemetry.span("elle.flatten"):
             self._ops = list(hist)
             try:
@@ -282,8 +296,9 @@ class DeviceAppendAnalysis:
         cycle: then no such order exists."""
         f = self.flat
         took = f.t_type == _TYPE_OK
-        seen = self.re_pid[self.re_pid >= 0] if len(self.re_pid) else \
-            np.empty(0, dtype=np.int64)
+        # the pids reads observed: a read element on its spine is the
+        # spine's pair, so the spine and the elements off it cover them
+        seen = np.concatenate([self.sp_pid, self.off_pid])
         if seen.size and len(self.w_txn):
             wt = self.w_txn[seen]
             wt = wt[wt >= 0]
@@ -320,22 +335,75 @@ class DeviceAppendAnalysis:
         ops = self._ops
         return [ops[p].index for p in self.flat.t_inv[rows].tolist()]
 
+    # -- version orders ----------------------------------------------------
+
+    def _spines(self):
+        """Each key's version order, its spine: the longest read of the
+        key, the earliest on ties (the host tie-break). It depends only
+        on the reads' keys and lengths, so it is chosen before any pair
+        is interned; then every read element is checked against the
+        spine element at its position."""
+        f = self.flat
+        R = len(f.rd_txn)
+        K = len(f.key_names)
+        self.spine_read = np.full(K, -1, dtype=np.int64)
+        self.spine_len = np.zeros(K, dtype=np.int64)
+        if R:
+            order = np.lexsort((np.arange(R), -f.rd_len, f.rd_key))
+            first = np.ones(R, dtype=bool)
+            kk = f.rd_key[order]
+            first[1:] = kk[1:] != kk[:-1]
+            sel = order[first]
+            keep = f.rd_len[sel] > 0
+            self.spine_read[kk[first][keep]] = sel[keep]
+            self.spine_len[kk[first][keep]] = f.rd_len[sel][keep]
+        # flat spine arrays, gathered from the spine reads' elements
+        skey = np.flatnonzero(self.spine_read >= 0)
+        srd = self.spine_read[skey]
+        lens = f.rd_len[srd]
+        off = np.cumsum(lens) - lens
+        self.sp_off = np.zeros(K, dtype=np.int64)
+        self.sp_off[skey] = off
+        self.sp_vals = f.re_vals[np.repeat(f.rd_off[srd] - off, lens)
+                                 + np.arange(int(lens.sum()))]
+        self.sp_keys = np.repeat(skey, lens)
+        # the prefix pass: element j of a read is element j of its key's
+        # spine, or the read is incompatible. Only the elements off the
+        # spine are interned on their own.
+        args = (f.rd_key, f.rd_len, self.sp_off, self.sp_vals, f.re_vals)
+        try:
+            self.off_elem = native.off_spine(*args)
+        except RuntimeError:
+            self.off_elem = _off_spine_py(*args)
+        self.off_read = f.re_read[self.off_elem]
+        telemetry.count("elle.read-elems", len(f.re_vals))
+        telemetry.count("elle.read-elems.off-spine", self.off_elem.size)
+
     # -- writers -----------------------------------------------------------
 
     def _resolve_writers(self):
         f = self.flat
         A = len(f.ap_txn)
-        ap_code = _pack(f.ap_key, f.ap_val)
-        re_code = (_pack(f.rd_key[f.re_read], f.re_vals)
-                   if len(f.re_vals) else np.empty(0, dtype=np.int64))
-        # dense pair ids over appends AND read elements, so value-based
-        # lookups (spine successors) work even for values no append
-        # wrote (the host engine keys its nxt dict by raw value)
-        codes = np.unique(np.concatenate([ap_code, re_code]))
+        S = len(self.sp_vals)
+        # dense pair ids over every (key, value) an append wrote or a
+        # read observed, so value-based lookups (spine successors) work
+        # even for values no append wrote (the host engine keys its nxt
+        # dict by raw value). A read element on its spine is the spine's
+        # pair, so appends, spines and the elements off them cover them.
+        codes, pid = np.unique(np.concatenate([
+            _pack(f.ap_key, f.ap_val), _pack(self.sp_keys, self.sp_vals),
+            _pack(f.rd_key[self.off_read], f.re_vals[self.off_elem])]),
+            return_inverse=True)
         self.pair_codes = codes            # sorted unique codes [P]
         P = len(codes)
-        inv = np.searchsorted(codes, ap_code)
-        self.ap_pid = inv                  # pid per append
+        self.ap_pid = inv = pid[:A]        # pid per append
+        self.sp_pid = pid[A:A + S]         # pid per spine element
+        self.off_pid = pid[A + S:]         # pid per element off its spine
+        # successor pid along each spine
+        self.pair_nxt = np.full(P, -1, dtype=np.int64)
+        if S > 1:
+            same = self.sp_keys[1:] == self.sp_keys[:-1]
+            self.pair_nxt[self.sp_pid[:-1][same]] = self.sp_pid[1:][same]
         order = np.arange(A)
         nonfail = f.t_type[f.ap_txn] != _TYPE_FAIL
         # writer append-row per pid: last non-fail, else first append;
@@ -398,116 +466,36 @@ class DeviceAppendAnalysis:
             if nf_k.size else np.empty((0, 2), dtype=np.int64)
         self.wk_key, self.wk_txn = kt[:, 0], kt[:, 1]
 
-    def _pid_of(self, keys, vals) -> np.ndarray:
-        """pid per (key, val); -1 only for pairs seen neither in an
-        append nor in any read (writerless pairs have a pid with
-        w_txn[pid] == -1)."""
-        codes = _pack(np.asarray(keys, dtype=np.int64),
-                      np.asarray(vals, dtype=np.int64))
-        if len(self.pair_codes) == 0:
-            return np.full(len(codes), -1, dtype=np.int64)
-        pos = np.searchsorted(self.pair_codes, codes)
-        pos = np.clip(pos, 0, len(self.pair_codes) - 1)
-        return np.where(self.pair_codes[pos] == codes, pos, -1)
-
-    # -- version orders ----------------------------------------------------
-
-    def _spines(self):
-        f = self.flat
-        R = len(f.rd_txn)
-        K = len(f.key_names)
-        # spine read per key: longest, earliest on ties (host tie-break)
-        self.spine_read = np.full(K, -1, dtype=np.int64)
-        self.spine_len = np.zeros(K, dtype=np.int64)
-        if R:
-            order = np.lexsort((np.arange(R), -f.rd_len, f.rd_key))
-            first = np.ones(R, dtype=bool)
-            kk = f.rd_key[order]
-            first[1:] = kk[1:] != kk[:-1]
-            sel = order[first]
-            keep = f.rd_len[sel] > 0
-            self.spine_read[kk[first][keep]] = sel[keep]
-            self.spine_len[kk[first][keep]] = f.rd_len[sel][keep]
-        # flat spine arrays
-        srd = self.spine_read[self.spine_read >= 0]
-        skey = np.flatnonzero(self.spine_read >= 0)
-        self.sp_key_of = skey
-        lens = f.rd_len[srd] if srd.size else np.empty(0, dtype=np.int64)
-        self.sp_off = np.zeros(K, dtype=np.int64)
-        off = np.concatenate([[0], np.cumsum(lens)])[:-1] \
-            if srd.size else np.empty(0, dtype=np.int64)
-        self.sp_off[skey] = off
-        # gather spine element values
-        idx = []
-        for r in srd:
-            idx.append(np.arange(f.rd_off[r], f.rd_off[r] + f.rd_len[r]))
-        self.sp_vals = (f.re_vals[np.concatenate(idx)] if idx
-                        else np.empty(0, dtype=np.int64))
-        self.sp_keys = np.repeat(skey, lens) if srd.size else \
-            np.empty(0, dtype=np.int64)
-        self.sp_pid = self._pid_of(self.sp_keys, self.sp_vals)
-        # successor pid along each spine
-        P = len(self.pair_codes)
-        self.pair_nxt = np.full(P, -1, dtype=np.int64)
-        if len(self.sp_pid) > 1:
-            same = self.sp_keys[1:] == self.sp_keys[:-1]
-            a = self.sp_pid[:-1][same]
-            b = self.sp_pid[1:][same]
-            good = a >= 0
-            self.pair_nxt[a[good]] = b[good]
-        # incompatible-order: each read must be a prefix of its spine
-        if R:
-            too_long = f.rd_len > self.spine_len[f.rd_key]
-            elem_pos = np.arange(len(f.re_vals)) - f.rd_off[f.re_read]
-            sp_at = self.sp_off[f.rd_key[f.re_read]] + elem_pos
-            in_range = elem_pos < self.spine_len[f.rd_key[f.re_read]]
-            if len(self.sp_vals):
-                sp_val = np.where(in_range, self.sp_vals[
-                    np.clip(sp_at, 0, len(self.sp_vals) - 1)], -1)
-            else:
-                sp_val = np.full(len(f.re_vals), -1, dtype=np.int64)
-            mismatch = np.where(in_range, sp_val != f.re_vals, True)
-            bad = too_long.copy()
-            np.logical_or.at(bad, f.re_read, mismatch)
-            for r in np.flatnonzero(bad):
-                o, n_ = int(f.rd_off[r]), int(f.rd_len[r])
-                k = int(f.rd_key[r])
-                so, sl = int(self.sp_off[k]), int(self.spine_len[k])
-                self.anomalies["incompatible-order"].append({
-                    "key": f.key_names[k],
-                    "read": f.re_vals[o:o + n_].tolist(),
-                    "spine": self.sp_vals[so:so + sl].tolist(),
-                    "op": self._op(f.rd_txn[r])})
-
     # -- read anomalies ----------------------------------------------------
 
     def _read_anomalies(self):
         f = self.flat
-        re_pid = self._pid_of(f.rd_key[f.re_read], f.re_vals)
-        self.re_pid = re_pid
-        # every read element has a pid now; writerless pairs carry -1
-        re_w = np.where(re_pid >= 0,
-                        self.w_txn[np.clip(re_pid, 0, None)]
-                        if len(self.w_txn) else -1, -1)
-        unobs = re_w < 0
-        for i in np.flatnonzero(unobs):
-            r = f.re_read[i]
-            self.anomalies["unobservable-read"].append({
-                "key": f.key_names[f.rd_key[r]],
-                "value": int(f.re_vals[i]), "op": self._op(f.rd_txn[r])})
-        aborted = np.zeros(len(re_pid), dtype=bool)
-        if len(self.w_txn):
-            aborted[~unobs] = self.w_fail[re_pid[~unobs]]
-        for i in np.flatnonzero(aborted):
-            r = f.re_read[i]
-            self.anomalies["G1a"].append({
-                "key": f.key_names[f.rd_key[r]],
-                "value": int(f.re_vals[i]), "op": self._op(f.rd_txn[r]),
-                "writer": self._op(self.w_txn[re_pid[i]])})
+        # incompatible-order: a read with an element off its spine
+        for r in np.unique(self.off_read):
+            o, n_ = int(f.rd_off[r]), int(f.rd_len[r])
+            k = int(f.rd_key[r])
+            so, sl = int(self.sp_off[k]), int(self.spine_len[k])
+            self.anomalies["incompatible-order"].append({
+                "key": f.key_names[k],
+                "read": f.re_vals[o:o + n_].tolist(),
+                "spine": self.sp_vals[so:so + sl].tolist(),
+                "op": self._op(f.rd_txn[r])})
+        # unobservable-read (a writerless pid) and G1a (a failed writer),
+        # per element in element order: flagged at each spine position,
+        # so only the reads whose prefix holds a flag, or that leave
+        # their spine, enumerate their elements
+        flag = (self.w_txn[self.sp_pid] < 0) | self.w_fail[self.sp_pid]
+        cum = np.concatenate([[0], np.cumsum(flag)])
+        lo = self.sp_off[f.rd_key]
+        hit = cum[lo + f.rd_len] > cum[lo]
+        hit[self.off_read] = True
+        self._element_anomalies(np.flatnonzero(hit))
         # G1b: last element is an intermediate version of another txn
         nz = np.flatnonzero(f.rd_len > 0)
-        last_idx = f.rd_off[nz] + f.rd_len[nz] - 1
-        last_pid = re_pid[last_idx]
+        last_pid = self.sp_pid[self.sp_off[f.rd_key[nz]] + f.rd_len[nz] - 1]
+        orow = self.off_read
+        last = self.off_elem == f.rd_off[orow] + f.rd_len[orow] - 1
+        last_pid[np.searchsorted(nz, orow[last])] = self.off_pid[last]
         self.nz_reads = nz
         self.last_pid = last_pid
         if not len(self.w_txn):
@@ -527,6 +515,32 @@ class DeviceAppendAnalysis:
                 "writer": self._op(self.w_txn[last_pid[i]])})
         for _ti, _kid, rec in f.internal_bad:
             self.anomalies["internal"].append(rec)
+
+    def _element_anomalies(self, rows):
+        """The unobservable-read and G1a records of the elements of read
+        rows (ascending), each element with its own pid."""
+        f = self.flat
+        lens = f.rd_len[rows]
+        base = np.cumsum(lens) - lens
+        step = np.arange(int(lens.sum())) - np.repeat(base, lens)
+        elem = np.repeat(f.rd_off[rows], lens) + step
+        rk = np.repeat(rows, lens)
+        pid = self.sp_pid[self.sp_off[f.rd_key[rk]] + step]
+        pid[np.searchsorted(elem, self.off_elem)] = self.off_pid
+        w = self.w_txn[pid]
+        for i in np.flatnonzero(w < 0):
+            r = rk[i]
+            self.anomalies["unobservable-read"].append({
+                "key": f.key_names[f.rd_key[r]],
+                "value": int(f.re_vals[elem[i]]),
+                "op": self._op(f.rd_txn[r])})
+        for i in np.flatnonzero(self.w_fail[pid]):
+            r = rk[i]
+            self.anomalies["G1a"].append({
+                "key": f.key_names[f.rd_key[r]],
+                "value": int(f.re_vals[elem[i]]),
+                "op": self._op(f.rd_txn[r]),
+                "writer": self._op(w[i])})
 
     # -- edges -------------------------------------------------------------
 

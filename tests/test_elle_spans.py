@@ -156,3 +156,43 @@ def test_spans_in_a_captured_trace(fresh, tmp_path):
     host = _host_events(tmp_path)
     for name, n in held.items():
         assert host[name] == n, (name, host[name], n)
+
+
+def _read_elems(hist) -> int:
+    return sum(len(m[2]) for o in hist if o.type == "ok"
+               for m in o.value if m[0] == "r" and m[2] is not None)
+
+
+def _off_spine_read(hist):
+    """The history with one more committed read, of the key with the
+    longest read: that read less its last element, with its first two
+    elements swapped. Returns it and the elements off the spine."""
+    ops = list(hist)
+    spine = max((m for o in ops if o.type == "ok" for m in o.value
+                 if m[0] == "r" and m[2] is not None),
+                key=lambda m: len(m[2]))
+    read = list(spine[2][:-1])
+    read[0], read[1] = read[1], read[0]
+    off = sum(a != b for a, b in zip(read, spine[2]))
+    n = len(ops)
+    ops += [op(index=n, time=n, type="invoke", process=902, f="txn",
+               value=[["r", spine[1], None]]),
+            op(index=n + 1, time=n + 1, type="ok", process=902, f="txn",
+               value=[["r", spine[1], read]])]
+    return History(ops), off
+
+
+def test_read_elements_resolve_by_spine_position(fresh):
+    hist = _valid()
+    res = elle.check_list_append(hist, {"certify": True})
+    assert res["valid?"] is True
+    c = fresh.counters()
+    assert c["elle.read-elems"] == _read_elems(hist) > 0
+    assert c["elle.read-elems.off-spine"] == 0
+    telemetry.reset()
+    hist, off = _off_spine_read(_valid())
+    res = elle.check_list_append(hist)
+    assert "incompatible-order" in res["anomaly-types"]
+    c = telemetry.get().counters()
+    assert c["elle.read-elems"] == _read_elems(hist)
+    assert c["elle.read-elems.off-spine"] == off == 2
