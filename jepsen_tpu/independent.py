@@ -17,13 +17,16 @@ mirror independent/tuple.
 
 from __future__ import annotations
 
+import logging
 from typing import Any, Callable, Iterable, Sequence
 
 from . import generator as gen
 from . import history as h
-from . import util
+from . import telemetry, util
 from .generator.context import make_thread_filter
 from .history import History
+
+logger = logging.getLogger(__name__)
 
 NEMESIS = gen.NEMESIS if hasattr(gen, "NEMESIS") else "nemesis"
 
@@ -249,50 +252,44 @@ class IndependentChecker:
         self.inner = inner
 
     def check(self, test, hist, opts=None):
+        """One `independent.check` span over the verdict: the split
+        (`independent.split`), the sub-checker's batch (or per-key)
+        checks, and the certificates' key stamps
+        (`independent.stamp`). Counts the keys (`independent.keys`) and
+        each history whose batch check raised
+        (`independent.batch-fallback`); the per-key results of such a
+        history carry `degradation`."""
+        with telemetry.span("independent.check"):
+            return self._check(test, hist, opts or {})
+
+    def _check(self, test, hist, opts):
         from . import checker as chk
 
-        opts = opts or {}
-        subs = subhistories(hist)
-        keys = sorted(subs.keys(), key=str)
+        with telemetry.span("independent.split"):
+            subs = subhistories(hist)
+            keys = sorted(subs.keys(), key=str)
+        telemetry.count("independent.keys", len(keys))
         results = None
+        fell_back = False
         if hasattr(self.inner, "check_batch"):
             try:
                 results = self.inner.check_batch(
                     test, [subs[k] for k in keys], opts)
             except Exception:  # noqa: BLE001 - retry with isolation
-                results = None
+                logger.warning("independent batch check raised; "
+                               "checking %d keys one by one", len(keys),
+                               exc_info=True)
+                telemetry.count("independent.batch-fallback")
+                fell_back = True
         if results is None:
             results = util.bounded_pmap(
                 lambda k: chk.check_safe(self.inner, test, subs[k], opts),
                 keys, limit=8)
+            if fell_back:
+                results = [_degraded(r) for r in results]
         by_key = dict(zip(keys, results))
-        # per-key verdict certificates reference the ORIGINAL history's
-        # op indices (subhistories keep them), but their values are
-        # wrapped (key, v) tuples there and their digest covers only
-        # the subhistory — stamp each certificate with its key (so the
-        # validator filters + unwraps during replay) and re-anchor the
-        # digest to the whole history the validator will be handed
-        # (jepsen_tpu.tpu.certify)
-        full_digest = None
-        for k, r in by_key.items():
-            cert = (r or {}).get("certificate") \
-                if isinstance(r, dict) else None
-            if isinstance(cert, dict) and "absent" not in cert:
-                from .tpu import certify as jcertify
-
-                try:
-                    import json as _json
-
-                    _json.dumps(k)
-                except (TypeError, ValueError):
-                    r["certificate"] = {"v": cert.get("v", 1),
-                                        "absent": "independent key "
-                                        "is not JSON-serializable"}
-                    continue
-                if full_digest is None:
-                    full_digest = jcertify.history_digest(hist)
-                cert["key"] = jcertify._jv(k)
-                cert["history"] = full_digest
+        with telemetry.span("independent.stamp"):
+            _stamp(hist, by_key)
         failures = [k for k, r in by_key.items()
                     if (r or {}).get("valid?") is False]
         valid = chk.merge_valid((r or {}).get("valid?")
@@ -300,6 +297,40 @@ class IndependentChecker:
         return {"valid?": valid,
                 "results": by_key,
                 "failures": failures}
+
+
+def _degraded(r):
+    """A per-key result of the fallback, stamped with its rung."""
+    if isinstance(r, dict):
+        r["degradation"] = list(r.get("degradation") or []) + [
+            "independent-batch-fallback"]
+    return r
+
+
+def _stamp(hist: History, by_key: dict) -> None:
+    """Per-key verdict certificates reference the ORIGINAL history's op
+    indices (subhistories keep them), but their values are wrapped
+    (key, v) tuples there and their digest covers only the subhistory:
+    stamps each certificate with its key (so the validator filters and
+    unwraps during replay) and re-anchors the digest to the whole
+    history the validator will be handed (jepsen_tpu.tpu.certify)."""
+    from .tpu import certify as jcertify
+
+    full_digest = None
+    for k, r in by_key.items():
+        cert = (r or {}).get("certificate") \
+            if isinstance(r, dict) else None
+        if not isinstance(cert, dict) or "absent" in cert:
+            continue
+        if not jcertify._jsonable(k):
+            r["certificate"] = {"v": cert.get("v", 1),
+                                "absent": "independent key "
+                                "is not JSON-serializable"}
+            continue
+        if full_digest is None:
+            full_digest = jcertify.history_digest(hist)
+        cert["key"] = jcertify._jv(k)
+        cert["history"] = full_digest
 
 
 def checker(inner) -> IndependentChecker:

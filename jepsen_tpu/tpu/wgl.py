@@ -1980,9 +1980,20 @@ def analysis_batch_streamed(model, hists: Sequence, chunk: int = 256,
     device search. A one-chunk drain lag keeps at most two chunks of
     packed tensors live on the host while preserving the overlap.
     certify=True attaches a per-result verdict certificate (the
-    checker batch path passes it; a raw call doesn't)."""
+    checker batch path passes it; a raw call doesn't).
+
+    Spans: `wgl.encode` and `wgl.pack` per chunk, `wgl.batch.drain`
+    (the wait for a chunk's launch and the decode of its results),
+    `wgl.batch.witness` (the host work on each member the kernel did
+    not decide VALID, unencodable members' model search included),
+    `certify.attach` per result. Counters: `wgl.batch.rows` (members
+    launched), `wgl.batch.unknown` (rows the kernel returned UNKNOWN)
+    and `wgl.batch.host` (members the host decided: UNKNOWN rows, rows
+    of a failed launch and unencodable members)."""
     hists = list(hists)
     results: list[dict] = [None] * len(hists)  # type: ignore
+    for name in ("wgl.batch.rows", "wgl.batch.unknown", "wgl.batch.host"):
+        telemetry.count(name, 0)
 
     def launch(group, start):
         encs = []
@@ -2000,7 +2011,9 @@ def analysis_batch_streamed(model, hists: Sequence, chunk: int = 256,
                 except EncodingError:
                     unencodable.append((i, hh))
         for i, hh in unencodable:
-            out = search_host_model(model, hh, witness=True)
+            telemetry.count("wgl.batch.host")
+            with telemetry.span("wgl.batch.witness"):
+                out = search_host_model(model, hh, witness=True)
             out["analyzer"] = "model"
             results[i] = _witness_op_indices(_search_stats(out))
             if certify_mod is not None:
@@ -2010,11 +2023,10 @@ def analysis_batch_streamed(model, hists: Sequence, chunk: int = 256,
         try:
             pb = PackedBatch(encs)
             rows = [(j, e.init_state) for j, e in enumerate(encs)]
-            return (_launch(pb, rows,
-                            W if W is not None else 32,
-                            F if F is not None else 64,
-                            reach=False),
-                    encs, idx_map, [])
+            dev = _launch(pb, rows, W if W is not None else 32,
+                          F if F is not None else 64, reach=False)
+            telemetry.count("wgl.batch.rows", len(rows))
+            return dev, encs, idx_map, []
         except RangeError:
             return None, encs, idx_map, []
         except Exception as e:  # noqa: BLE001 — device ladder
@@ -2027,25 +2039,30 @@ def analysis_batch_streamed(model, hists: Sequence, chunk: int = 256,
 
     def drain(entry):
         dev, encs, idx_map, rungs = entry
-        if dev is not None:
-            try:
-                res = _drain(dev, reach=False)[:len(encs)]
-            except Exception as e:  # noqa: BLE001 — async dispatch
-                # defers device failure to the blocking drain
-                rungs = rungs + [_ladder_classify(e, "streamed drain")]
-                res = [UNKNOWN] * len(encs)
-        else:
+        with telemetry.span("wgl.batch.drain"):
             res = [UNKNOWN] * len(encs)
+            if dev is not None:
+                try:
+                    res = _drain(dev, reach=False)[:len(encs)]
+                    telemetry.count("wgl.batch.unknown", int(
+                        np.count_nonzero(res == UNKNOWN)))
+                except Exception as e:  # noqa: BLE001 — async dispatch
+                    # defers device failure to the blocking drain
+                    rungs = rungs + [_ladder_classify(e,
+                                                      "streamed drain")]
         for j, i in enumerate(idx_map):
             r = int(res[j])
             own = list(rungs)
             if r == VALID:
                 results[i] = {"valid?": True, "analyzer": "tpu"}
             else:
+                if r != INVALID:
+                    telemetry.count("wgl.batch.host")
                 # Bounded: long invalid/unknown members are localized
                 # segment-wise instead of re-searched whole on host,
                 # keeping the caller's W/F tuning.
-                with _ladder_fork() as sub:
+                with telemetry.span("wgl.batch.witness"), \
+                        _ladder_fork() as sub:
                     # rungs the witness extraction itself walked (e.g.
                     # a segmented-search device failure) belong to
                     # THIS result too

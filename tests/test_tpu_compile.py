@@ -14,6 +14,9 @@ configs' widths, recorded from a CPU run of the same phases (PR 21):
     states, 128 search rows, W=24/F=48, reach mode, no crashed ops;
   - config 5 (1024 x 400-op histories, 15% crashes): each 128-history
     chunk packs 129 x 512 entries, 8 states, 128 rows, W=32/F=64;
+  - the indep-register-410k-partition cell (one 819k-event history of
+    4,038 independent keys, crashed ops): one launch of every key,
+    4,039 x 512 entries, 8 states, 4096 rows, W=32/F=64;
   - config 3 (100k list-append txns): the SCC graph pads to 131072
     nodes and 655360 edges;
   - on a 2x2 mesh, the blocked layouts of the same launches.
@@ -92,6 +95,7 @@ def _wgl_shapes(K, M, S, rows):
 WGL_LAUNCHES = {
     "config2-segmented": (33, 16384, 8, 128, 24, 48, True, True),
     "config5-batch": (129, 512, 8, 128, 32, 64, False, False),
+    "indep-410k-batch": (4039, 512, 8, 4096, 32, 64, False, False),
 }
 
 
