@@ -1153,17 +1153,21 @@ def _witness_from_best(enc, best, model_name: str) -> dict:
 
 
 def wgl_certificate(model, hist, enc, result,
-                    prefetch: Prefetch | None = None) -> dict:
+                    prefetch: Prefetch | None = None,
+                    order: list | None = None) -> dict:
     """Builds the certificate for one wgl analysis result. Valid
     verdicts get a per-segment linearization order guided by the
     result's reach/choice chain (`search-chain`, recorded by
     check_segmented) when present; invalid verdicts a replayable
     blocked-frontier witness. Failure to extract returns an honest
     absent marker, never raises. `prefetch`: the verdict's Prefetch,
-    whose digest and orders are used where it has them; the
-    certificate is the same with or without it."""
+    whose digest and orders are used where it has them; `order`: the
+    actions `_order_search(enc)` already found for a valid verdict
+    without a chain (wgl.decide_unknown). The certificate is the same
+    with or without either."""
     try:
-        return _wgl_certificate(model, hist, enc, result, prefetch)
+        return _wgl_certificate(model, hist, enc, result, prefetch,
+                                order)
     except _Budget:
         return absent("search-budget-exceeded")
     except CertificateError as e:
@@ -1190,7 +1194,8 @@ def _chain_segments(enc, chain_info, n: int, prefetch, fault) -> list:
     return out
 
 
-def _wgl_certificate(model, hist, enc, result, prefetch=None) -> dict:
+def _wgl_certificate(model, hist, enc, result, prefetch=None,
+                     order=None) -> dict:
     verdict = result.get("valid?")
     if verdict not in (True, False):
         return absent("verdict is indeterminate")
@@ -1218,7 +1223,8 @@ def _wgl_certificate(model, hist, enc, result, prefetch=None) -> dict:
                           f"{chain[j]} to {chain[j + 1]} — the reach "
                           "chain lies")
         else:
-            ok, actions = _order_search(enc)
+            ok, actions = ((True, order) if order is not None
+                           else _order_search(enc))
             if not ok:
                 raise CertificateError(
                     "no whole-history linearization found for a "
@@ -1258,11 +1264,16 @@ def attach_wgl(model, hist, enc, result,
     points call this; raw kernel paths don't). Disabled
     extraction still leaves an honest absent marker so result walks
     stay uniform. `prefetch`: the verdict's Prefetch (wgl.analysis),
-    or None. Counts the segment orders of a certificate made
+    or None. The order wgl.decide_unknown carried on the result
+    (under wgl.ORDER_KEY) is taken off it and used as the certificate's
+    order. Counts the segment orders of a certificate made
     (`certify.segments`) and how many of them the prefetch had ready
     (`certify.segments.prefetched`)."""
     if not isinstance(result, dict):
         return result
+    from .wgl import ORDER_KEY
+
+    order = result.pop(ORDER_KEY, None)
     if not enabled():
         result["certificate"] = absent("extraction disabled "
                                        "(JEPSEN_TPU_CERTIFY=0)")
@@ -1271,7 +1282,8 @@ def attach_wgl(model, hist, enc, result,
     # separately from device compute (flightrec.kernel_phases joins
     # this span against the launch window)
     with telemetry.span("certify.attach"):
-        cert = wgl_certificate(model, hist, enc, result, prefetch)
+        cert = wgl_certificate(model, hist, enc, result, prefetch,
+                               order)
     result["certificate"] = cert
     if "absent" in cert:
         telemetry.count("certify.absent")

@@ -294,8 +294,13 @@ def analysis_batch_sharded(model, hists, mesh=None, W: int | None = None,
             else:
                 # Bounded anomaly path: localize the failing segment on
                 # device instead of re-searching the whole history on
-                # host (unbounded at 1M-op scale).
-                out = wgl_mod.extract_witness(encs[j], W=W, F=F)
+                # host (unbounded at 1M-op scale). No certificate here,
+                # so an UNKNOWN member's order is dropped.
+                if r == wgl_mod.INVALID:
+                    out = wgl_mod.extract_witness(encs[j], W=W, F=F)
+                else:
+                    out = wgl_mod.decide_unknown(encs[j], W=W, F=F)
+                    out.pop(wgl_mod.ORDER_KEY, None)
                 out["analyzer"] = ("tpu-sharded" if r == wgl_mod.INVALID
                                    else "tpu+host-fallback")
                 results[i] = wgl_mod._search_stats(out)

@@ -1781,6 +1781,41 @@ def extract_witness(enc: Encoded, W: int | None = None,
     return _witness_op_indices(out)
 
 
+# Where decide_unknown's order rides on a valid result until
+# certify.attach_wgl takes it off as the key's certificate order.
+ORDER_KEY = "_order"
+
+
+def decide_unknown(enc: Encoded, W: int | None = None,
+                   F: int | None = None) -> dict:
+    """Decides a batch member the kernel returned UNKNOWN.
+
+    A history below SEGMENT_MIN_M first gets the certificate's order
+    search (certify._order_search: search_host's configurations and
+    moves, walked lazily, earliest candidate first, apply before
+    discard), which finds a valid history's linearization long before
+    search_host's latest-first DFS does. An order found decides the
+    member valid (`witness-extraction` 'order') and rides on the result
+    under ORDER_KEY for the certificate. No order, the search's node
+    budget spent, or a longer history falls through to
+    extract_witness, so every invalid result is extract_witness's.
+    Counters: `wgl.batch.order` (decided by the order search),
+    `wgl.batch.order-miss` (fell through)."""
+    if enc.m < SEGMENT_MIN_M:
+        from . import certify
+
+        try:
+            found, actions = certify._order_search(enc)
+        except certify._Budget:
+            found = False
+        if found:
+            telemetry.count("wgl.batch.order")
+            return {"valid?": True, "witness-extraction": "order",
+                    ORDER_KEY: actions}
+    telemetry.count("wgl.batch.order-miss")
+    return extract_witness(enc, W=W, F=F)
+
+
 def _search_stats(out: dict) -> dict:
     """Attaches out['search'] — the witness-position percentile
     ("nonlinearizable witnessed at 12% of the history") for invalid
@@ -1987,12 +2022,15 @@ def analysis_batch_streamed(model, hists: Sequence, chunk: int = 256,
     `wgl.batch.witness` (the host work on each member the kernel did
     not decide VALID, unencodable members' model search included),
     `certify.attach` per result. Counters: `wgl.batch.rows` (members
-    launched), `wgl.batch.unknown` (rows the kernel returned UNKNOWN)
-    and `wgl.batch.host` (members the host decided: UNKNOWN rows, rows
-    of a failed launch and unencodable members)."""
+    launched), `wgl.batch.unknown` (rows the kernel returned UNKNOWN),
+    `wgl.batch.host` (members the host decided: UNKNOWN rows, rows
+    of a failed launch and unencodable members) and decide_unknown's
+    `wgl.batch.order` and `wgl.batch.order-miss`, which sum to
+    `wgl.batch.unknown` where no launch failed."""
     hists = list(hists)
     results: list[dict] = [None] * len(hists)  # type: ignore
-    for name in ("wgl.batch.rows", "wgl.batch.unknown", "wgl.batch.host"):
+    for name in ("wgl.batch.rows", "wgl.batch.unknown", "wgl.batch.host",
+                 "wgl.batch.order", "wgl.batch.order-miss"):
         telemetry.count(name, 0)
 
     def launch(group, start):
@@ -2066,7 +2104,9 @@ def analysis_batch_streamed(model, hists: Sequence, chunk: int = 256,
                     # rungs the witness extraction itself walked (e.g.
                     # a segmented-search device failure) belong to
                     # THIS result too
-                    out = extract_witness(encs[j], W=W, F=F)
+                    out = (extract_witness(encs[j], W=W, F=F)
+                           if r == INVALID
+                           else decide_unknown(encs[j], W=W, F=F))
                 own += sub
                 out["analyzer"] = ("tpu" if r == INVALID
                                    else "tpu+host-fallback")
@@ -2082,6 +2122,8 @@ def analysis_batch_streamed(model, hists: Sequence, chunk: int = 256,
             if certify_mod is not None:
                 certify_mod.attach_wgl(model, hists[i], encs[j],
                                        results[i])
+            else:
+                results[i].pop(ORDER_KEY, None)
 
     with _ladder_scope():
         pending = None

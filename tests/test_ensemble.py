@@ -231,3 +231,45 @@ class TestStreamedBatch:
         assert [r["valid?"] for r in one] == \
             [r["valid?"] for r in streamed]
         assert streamed[7]["valid?"] is False
+
+
+def test_sharded_batch_decides_unknown_members_alike(monkeypatch):
+    """The sharded batch path decides the members its launch left
+    UNKNOWN through wgl.decide_unknown, as the streamed path does: the
+    same calls, the same order counters, the same results."""
+    from jepsen_tpu import telemetry
+
+    m = model.cas_register()
+    hists = [synth.register_history(40, n_procs=5, seed=s, crash_p=0.2)
+             for s in range(1, 7)]
+    hists[2] = corrupt(hists[2])
+    hists[5] = corrupt(hists[5])
+    calls = []
+    real = wgl.decide_unknown
+
+    def spy(enc, W=None, F=None):
+        calls.append(enc.m)
+        return real(enc, W=W, F=F)
+
+    monkeypatch.setattr(wgl, "decide_unknown", spy)
+    monkeypatch.setattr(wgl, "_launch", lambda pb, rows, *a, **kw: len(rows))
+    monkeypatch.setattr(wgl, "_drain",
+                        lambda n, reach: np.full(n, wgl.UNKNOWN, np.int32))
+    monkeypatch.setattr(ensemble, "check_batch_sharded",
+                        lambda encs, **kw: np.full(len(encs), wgl.UNKNOWN))
+    names = ("wgl.batch.order", "wgl.batch.order-miss")
+    runs = []
+    for check in (wgl.analysis_batch_streamed,
+                  ensemble.analysis_batch_sharded):
+        telemetry.reset()
+        calls.clear()
+        res = check(m, hists)
+        c = telemetry.get().counters()
+        runs.append((res, [c.get(n, 0) for n in names], list(calls)))
+    (streamed, c_streamed, k_streamed), (sharded, c_sharded, k_sharded) = runs
+    assert sharded == streamed
+    assert c_sharded == c_streamed == [4, 2]
+    assert k_sharded == k_streamed and len(k_sharded) == len(hists)
+    assert [r["valid?"] for r in sharded] == [True, True, False, True,
+                                              True, False]
+    assert all(wgl.ORDER_KEY not in r for r in sharded)
